@@ -1,43 +1,38 @@
 """Paper Table 7: single vs double precision — time and accuracy.
 
 Paper: fp64 ~2x slower on Fermi, ~100x lower error; fp32 "enough for SA's
-purpose".  We reproduce both directions.  x64 is enabled in a subprocess so
-the global jax config of the benchmark process is untouched.
+purpose".  We reproduce both directions.  Both precisions run in this
+process — one process per chip — with the float64 case inside a
+``jax.enable_x64`` scope, so the global config is untouched afterwards.
 """
 from __future__ import annotations
 
-import json
-import subprocess
-import sys
-from pathlib import Path
+import time
 
-from .common import Budget, Table
-
-_CHILD = r"""
-import json, sys, time
 import jax
-if sys.argv[1] == "float64":
-    jax.config.update("jax_enable_x64", True)
+
 from repro.core import SAConfig, sa_minimize
 from repro.objectives import functions as F
 
-dtype = sys.argv[1]
-quick = sys.argv[2] == "quick"
-obj = F.schwefel(16)
-if quick:
-    cfg = SAConfig(T0=100.0, T_min=0.05, rho=0.9, N=30, n_chains=1024,
-                   dtype=dtype, record_history=False)
-else:
-    cfg = SAConfig(T0=1000.0, T_min=0.01, rho=0.99, N=100, n_chains=16384,
-                   dtype=dtype, record_history=False)
-res = sa_minimize(obj, cfg, key=jax.random.PRNGKey(0))  # warm compile
-t0 = time.time()
-res = sa_minimize(obj, cfg, key=jax.random.PRNGKey(1))
-dt = time.time() - t0
-df, dx = obj.error_to_opt(res.x_best, res.f_best)
-print(json.dumps({"dtype": dtype, "time_s": dt,
-                  "f_err": float(df), "x_err": float(dx)}))
-"""
+from .common import Budget, Table
+
+
+def _run_one(dtype: str, quick: bool) -> dict:
+    obj = F.schwefel(16)
+    if quick:
+        cfg = SAConfig(T0=100.0, T_min=0.05, rho=0.9, N=30, n_chains=1024,
+                       dtype=dtype, record_history=False)
+    else:
+        cfg = SAConfig(T0=1000.0, T_min=0.01, rho=0.99, N=100,
+                       n_chains=16384, dtype=dtype, record_history=False)
+    sa_minimize(obj, cfg, key=jax.random.PRNGKey(0))  # warm compile
+    t0 = time.perf_counter()
+    res = sa_minimize(obj, cfg, key=jax.random.PRNGKey(1))
+    jax.block_until_ready(res.f_best)
+    dt = time.perf_counter() - t0
+    df, dx = obj.error_to_opt(res.x_best, res.f_best)
+    return {"dtype": dtype, "time_s": dt, "f_err": float(df),
+            "x_err": float(dx)}
 
 
 def run(budget: Budget) -> Table:
@@ -45,14 +40,9 @@ def run(budget: Budget) -> Table:
               ["precision", "time_s", "|f-f*|", "rel-x err"],
               fmt={"time_s": ".2f", "|f-f*|": ".3e", "rel-x err": ".3e"})
     rows = {}
-    src = Path(__file__).resolve().parent.parent / "src"
     for dtype in ("float32", "float64"):
-        out = subprocess.run(
-            [sys.executable, "-c", _CHILD, dtype, budget.label],
-            capture_output=True, text=True,
-            env={"PYTHONPATH": str(src), "PATH": "/usr/bin:/bin"},
-            check=True)
-        r = json.loads(out.stdout.strip().splitlines()[-1])
+        with jax.enable_x64(dtype == "float64"):
+            r = _run_one(dtype, budget.quick)
         rows[dtype] = r
         t.add(precision=dtype, time_s=r["time_s"], **{"|f-f*|": r["f_err"],
                                                       "rel-x err": r["x_err"]})

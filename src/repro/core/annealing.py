@@ -196,8 +196,7 @@ def build_sharded_ladder(objective: Objective, cfg: SAConfig,
         return bx, bf, hist
 
     hist_spec = P() if cfg_l.record_history else ()
-    from repro.launch.mesh import shard_map
-    return shard_map(
+    return jax.shard_map(
         sharded, mesh=mesh,
         in_specs=(P(), P(axes)),
         out_specs=(P(), P(), hist_spec),

@@ -19,14 +19,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 
-def _axis_size(axis: str):
-    """``lax.axis_size`` with an older-jax fallback (psum of ones — the
-    classic spelling; same traced value inside a mapped axis)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
 def pipeline_apply(layer_fn, stage_params, x_microbatches, *, axis: str = "pod"):
     """Run inside shard_map: stage_params holds THIS pod's stage weights;
     x_microbatches: (M, mb, ...) microbatch queue (replicated content).
@@ -34,7 +26,7 @@ def pipeline_apply(layer_fn, stage_params, x_microbatches, *, axis: str = "pod")
     Returns the final-stage outputs for every microbatch (valid on the last
     stage; other stages return the in-flight values).
     """
-    n_stages = _axis_size(axis)
+    n_stages = lax.axis_size(axis)
     stage = lax.axis_index(axis)
     M = x_microbatches.shape[0]
     ticks = M + n_stages - 1
@@ -75,14 +67,13 @@ def make_pipelined_fn(layer_fn, mesh, *, axis: str = "pod",
         out = pipeline_apply(layer_fn, stage_params, xs, axis=axis)
         # broadcast final-stage outputs to all stages for a replicated
         # return (mask + psum: ppermute can't fan out one source to many)
-        n = _axis_size(axis)
+        n = lax.axis_size(axis)
         last = (lax.axis_index(axis) == n - 1).astype(out.dtype)
         return lax.psum(out * last, axis)
 
-    from repro.launch.mesh import shard_map
-    return shard_map(fn, mesh=mesh,
-                     in_specs=(stage_param_spec, x_spec),
-                     out_specs=x_spec, check_vma=False)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(stage_param_spec, x_spec),
+                         out_specs=x_spec, check_vma=False)
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:

@@ -78,82 +78,59 @@ def _step_draws(seed, cidx, step0, i):
     return rng.draws3(seed, cidx, (step0 + i).astype(jnp.uint32))
 
 
-def _sweep_kernel(*refs, kid_static, n_steps: int, blk: int,
-                  variant: str, with_live: bool = False,
-                  with_chain_t: bool = False):
-    # Ref layout: 5-or-6 SMEM control refs, then the VMEM tensor refs.
-    # ``live`` (macro-tick serving path) is the per-slot level cursor —
-    # blocks whose request has exhausted its planned ladder levels for
-    # this macro-tick pass their state through bit-exactly (acc forced
-    # to False; the counter-based RNG is stateless so no draws are
-    # consumed on their behalf).  ``with_chain_t`` (replica-exchange
-    # serving path) swaps the per-block SMEM temperature for a (blk, 1)
-    # VMEM column so every chain — a parallel-tempering rung — anneals at
-    # its own temperature inside one block.
-    n_smem = 6 if with_live else 5
-    kid_ref, seed_ref, step0_ref, t_ref, base_ref = refs[:5]
-    live_ref = refs[5] if with_live else None
-    vrefs = refs[n_smem:]
-    if with_chain_t:
-        x_ref, tc_ref, xo_ref, fo_ref = vrefs
-    else:
-        x_ref, xo_ref, fo_ref = vrefs
-        tc_ref = None
-    dim = x_ref.shape[-1]
+#: Objective math per dispatch surface (objective_math): a concrete
+#: Python-int ``kid`` traces one branch, a traced ``kid`` all of them.
+STATIC_FNS = (om.init_acc, om.combine, om.term, om.full_eval)
+RUNTIME_FNS = (om.init_acc_rt, om.combine_rt, om.term_rt, om.full_eval_rt)
 
-    pid = pl.program_id(0)
-    if kid_static is not None:
-        # Concrete objective: compile the single branch (pre-runtime-dispatch
-        # behavior — batch callers keep 1x objective math per proposal).
-        kid = kid_static
-        lo, hi = om.BOX[kid]
-        lo, hi = np.float32(lo), np.float32(hi)
-        init_acc, combine, term, full_eval = (
-            om.init_acc, om.combine, om.term, om.full_eval)
-    else:
-        kid = kid_ref[pid]      # runtime objective id: scalar per block
-        lo, hi = om.box_rt(kid)
-        init_acc, combine, term, full_eval = (
-            om.init_acc_rt, om.combine_rt, om.term_rt, om.full_eval_rt)
-    seed = seed_ref[pid]
-    step0 = step0_ref[pid]
-    # Per-chain (blk, 1) temperature column, or the block's SMEM scalar —
-    # broadcasting against the (blk, 1) accept shapes either way.
-    T = t_ref[pid] if tc_ref is None else tc_ref[...]
-    base = base_ref[pid]
-    live = None if live_ref is None else live_ref[pid] != 0
-    cidx = base + lax.broadcasted_iota(jnp.int32, (blk, 1), 0).astype(jnp.uint32)
-    coords = lax.broadcasted_iota(jnp.int32, (blk, dim), 1)
 
-    x = x_ref[...]
+def sweep_chains(x, T, seed, cidx, step0, *, kid, lo, hi, fns,
+                 n_steps: int, variant: str, live=None,
+                 reduce=om.lane_sum):
+    """``n_steps`` Metropolis steps for a ``(rows, dim)`` batch of chains.
+
+    The one definition of the sweep: the Pallas kernel runs it on one
+    chain-block with SMEM scalars, the oracle (``ref.py``) on the whole
+    batch with per-chain ``(rows, 1)`` columns, so the two agree by
+    construction.  ``fns`` is :data:`STATIC_FNS` or :data:`RUNTIME_FNS`;
+    ``lo``/``hi`` is the box, broadcastable to ``(rows, 1)``; ``live``
+    (optional) masks every accept of a dead row, which then passes
+    through bit-exactly.  ``reduce`` is the accumulator init's lane sum
+    (``objective_math.init_acc``).
+
+    Returns ``(x, fx)`` with ``fx`` shaped ``(rows, 1)``.
+    """
+    init_acc, combine, term, full_eval = fns
+    rows, dim = x.shape
+    coords = lax.broadcasted_iota(jnp.int32, (rows, dim), 1)
 
     if variant == "delta":
-        S, logP, sgnP = init_acc(kid, x)
+        S, logP, sgnP = init_acc(kid, x, reduce)
         fx = combine(kid, S, logP, sgnP, dim)
 
         def body(i, carry):
             x, fx, S, logP, sgnP = carry
             rbits, uval, uacc = _step_draws(seed, cidx, step0, i)
-            d = (rbits % np.uint32(dim)).astype(jnp.int32)  # (blk, 1)
+            d = (rbits % np.uint32(dim)).astype(jnp.int32)  # (rows, 1)
             onehot = coords == d
             xi_old = jnp.sum(jnp.where(onehot, x, 0.0), axis=1, keepdims=True)
             newval = lo + uval * (hi - lo)
             df = d.astype(x.dtype)
             s_old, p_old = term(kid, xi_old, df)
             s_new, p_new = term(kid, newval, df)
-            S1 = S - s_old + s_new
+            S1 = tuple(a - o + n for a, o, n in zip(S, s_old, s_new))
             logP1 = (logP
                      - jnp.log(jnp.maximum(jnp.abs(p_old), 1e-30))
                      + jnp.log(jnp.maximum(jnp.abs(p_new), 1e-30)))
             sg = jnp.where(p_old < 0, -1.0, 1.0) * jnp.where(p_new < 0, -1.0, 1.0)
             sgnP1 = sgnP * sg.astype(sgnP.dtype)
             f1 = combine(kid, S1, logP1, sgnP1, dim)
-            acc = uacc <= _accept_prob(fx, f1, T)  # (blk, 1)
+            acc = uacc <= _accept_prob(fx, f1, T)  # (rows, 1)
             if live is not None:
                 acc = acc & live
             x = jnp.where(onehot & acc, newval, x)
             fx = jnp.where(acc, f1, fx)
-            S = jnp.where(acc, S1, S)
+            S = tuple(jnp.where(acc, a1, a) for a1, a in zip(S1, S))
             logP = jnp.where(acc, logP1, logP)
             sgnP = jnp.where(acc, sgnP1, sgnP)
             return x, fx, S, logP, sgnP
@@ -178,7 +155,51 @@ def _sweep_kernel(*refs, kid_static, n_steps: int, blk: int,
             return x, fx
 
         x, fx = lax.fori_loop(0, n_steps, body, (x, fx))
+    return x, fx
 
+
+def _sweep_kernel(*refs, kid_static, n_steps: int, blk: int,
+                  variant: str, with_live: bool = False,
+                  with_chain_t: bool = False):
+    # Ref layout: 5-or-6 SMEM control refs, then the VMEM tensor refs.
+    # ``live`` (macro-tick serving path) is the per-slot level cursor —
+    # blocks whose request has exhausted its planned ladder levels for
+    # this macro-tick pass their state through bit-exactly (acc forced
+    # to False; the counter-based RNG is stateless so no draws are
+    # consumed on their behalf).  ``with_chain_t`` (replica-exchange
+    # serving path) swaps the per-block SMEM temperature for a (blk, 1)
+    # VMEM column so every chain — a parallel-tempering rung — anneals at
+    # its own temperature inside one block.
+    n_smem = 6 if with_live else 5
+    kid_ref, seed_ref, step0_ref, t_ref, base_ref = refs[:5]
+    live_ref = refs[5] if with_live else None
+    vrefs = refs[n_smem:]
+    if with_chain_t:
+        x_ref, tc_ref, xo_ref, fo_ref = vrefs
+    else:
+        x_ref, xo_ref, fo_ref = vrefs
+        tc_ref = None
+
+    pid = pl.program_id(0)
+    if kid_static is not None:
+        # Concrete objective: compile the single branch (pre-runtime-dispatch
+        # behavior — batch callers keep 1x objective math per proposal).
+        kid = kid_static
+        lo, hi = (np.float32(b) for b in om.BOX[kid])
+        fns = STATIC_FNS
+    else:
+        kid = kid_ref[pid]      # runtime objective id: scalar per block
+        lo, hi = om.box_rt(kid)
+        fns = RUNTIME_FNS
+    # Per-chain (blk, 1) temperature column, or the block's SMEM scalar —
+    # broadcasting against the (blk, 1) accept shapes either way.
+    T = t_ref[pid] if tc_ref is None else tc_ref[...]
+    live = None if live_ref is None else live_ref[pid] != 0
+    cidx = (base_ref[pid]
+            + lax.broadcasted_iota(jnp.int32, (blk, 1), 0).astype(jnp.uint32))
+    x, fx = sweep_chains(x_ref[...], T, seed_ref[pid], cidx, step0_ref[pid],
+                         kid=kid, lo=lo, hi=hi, fns=fns, n_steps=n_steps,
+                         variant=variant, live=live)
     xo_ref[...] = x
     fo_ref[...] = fx
 

@@ -4,9 +4,15 @@ Shared by the Pallas kernel (``metropolis_sweep.py``) and the pure-jnp
 oracle (``ref.py``) so both compute identical floating-point expressions.
 
 Accumulator layout (uniform across objectives, unused slots stay zero):
-  S    : (..., 2)  sum accumulators
+  S    : pair of (..., 1) sum accumulators (S0, S1)
   logP : (..., 1)  log-magnitude of the product accumulator
   sgnP : (..., 1)  sign (+-1) of the product accumulator
+
+Every expression stays on 2-D ``(chains, dim)`` tiles or ``(chains, 1)``
+columns: rank-3 ``(..., dim, k)`` intermediates pad every ``(dim, k)``
+slice to a full vreg tile, which at ``blk=256`` overflows VMEM.  The
+Griewank product is a static tree of lane slices (``_lane_tree``): the
+Pallas TPU lowering has no ``reduce_prod``.
 
 Two dispatch surfaces per primitive:
 
@@ -31,7 +37,10 @@ Two dispatch surfaces per primitive:
 """
 from __future__ import annotations
 
+import operator
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -82,8 +91,8 @@ def full_eval(kid: int, x, dim: int):
         # captured non-scalar constants, so the index vector must be an op.
         i = lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1).astype(x.dtype)
         s = jnp.sum(x * x, -1, keepdims=True) / 4000.0
-        p = jnp.prod(jnp.cos(x / jnp.sqrt(i + 1.0)), -1, keepdims=True)
-        f = 1.0 + s - p
+        f = 1.0 + s - _lane_tree(jnp.cos(x / jnp.sqrt(i + 1.0)),
+                                  operator.mul)
     elif kid == KID_EXPONENTIAL:
         f = -jnp.exp(-0.5 * jnp.sum(x * x, -1, keepdims=True))
     elif kid == KID_SALOMON:
@@ -94,52 +103,92 @@ def full_eval(kid: int, x, dim: int):
     return f.astype(x.dtype)
 
 
+def _lane_tree(v, op):
+    """Reduce the last axis with ``op`` over a static halving tree of lane
+    slices: ``log2(dim)`` full-width elementwise steps in an order fixed
+    here, so every program rounds them the same way whatever its shape."""
+    odd = []
+    while v.shape[-1] > 1:
+        w = v.shape[-1]
+        h = w // 2
+        if w % 2:
+            odd.append(v[..., w - 1:w])
+        v = op(v[..., :h], v[..., h:2 * h])
+    for col in odd:
+        v = op(v, col)
+    return v
+
+
+def lane_sum(v):
+    """Sum over the last axis, keepdims: the kernel's lane reduction."""
+    return jnp.sum(v, -1, keepdims=True)
+
+
+def lane_sum_tree(v):
+    """Sum over the last axis, keepdims, in :func:`_lane_tree` order: the
+    oracle's form.  XLA:CPU rounds a fused row reduction differently from
+    one program shape to another, and the serving oracle compares a
+    packed batch with a standalone one bitwise."""
+    return _lane_tree(v, operator.add)
+
+
 def term(kid: int, xi, d):
-    """Per-coordinate contributions. xi, d: (..., 1). Returns (s (...,2), p (...,1))."""
+    """Per-coordinate contributions, elementwise over any shape.
+
+    Returns ``((s0, s1), p)``: the two sum terms and the product term,
+    each shaped like ``xi``.
+    """
     z = jnp.zeros_like(xi)
+    one = jnp.ones_like(xi)
     if kid == KID_SCHWEFEL:
-        return jnp.concatenate([xi * jnp.sin(jnp.sqrt(jnp.abs(xi))), z], -1), jnp.ones_like(xi)
+        return (xi * jnp.sin(jnp.sqrt(jnp.abs(xi))), z), one
     if kid == KID_RASTRIGIN:
-        return jnp.concatenate([xi * xi - 10.0 * jnp.cos(2 * _PI * xi), z], -1), jnp.ones_like(xi)
+        return (xi * xi - 10.0 * jnp.cos(2 * _PI * xi), z), one
     if kid == KID_ACKLEY:
-        return jnp.concatenate([xi * xi, jnp.cos(2 * _PI * xi)], -1), jnp.ones_like(xi)
+        return (xi * xi, jnp.cos(2 * _PI * xi)), one
     if kid == KID_GRIEWANK:
-        s = jnp.concatenate([xi * xi / 4000.0, z], -1)
         p = jnp.cos(xi / jnp.sqrt(d.astype(xi.dtype) + 1.0))
-        return s, p
+        return (xi * xi / 4000.0, z), p
     if kid in (KID_EXPONENTIAL, KID_SALOMON):
         # Both reduce to the radial sum S0 = Σ x_i²; combine() does the rest.
-        return jnp.concatenate([xi * xi, z], -1), jnp.ones_like(xi)
+        return (xi * xi, z), one
     raise ValueError(f"unknown kernel objective id {kid}")
 
 
-def init_acc(kid: int, x):
-    """Exact O(dim) accumulator init from the state block x: (..., dim)."""
+def init_acc(kid: int, x, reduce=lane_sum):
+    """Exact O(dim) accumulator init from the state block x: (..., dim).
+
+    ``reduce`` is :func:`lane_sum` (the kernel) or :func:`lane_sum_tree`
+    (the oracle).
+    """
     d = lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1).astype(x.dtype)
-    # term() over every coordinate: reshape to (..., dim, 1)
-    s, p = term(kid, x[..., None], d[..., None])  # (..., dim, 2), (..., dim, 1)
-    S = jnp.sum(s, axis=-2)
-    logP = jnp.sum(jnp.log(jnp.maximum(jnp.abs(p), _TINY)), axis=-2)
-    sgnP = jnp.prod(jnp.where(p < 0, -1.0, 1.0).astype(x.dtype), axis=-2)
-    return S, logP, sgnP
+    (s0, s1), p = term(kid, x, d)
+    S = (reduce(s0), reduce(s1))
+    logP = reduce(jnp.log(jnp.maximum(jnp.abs(p), _TINY)))
+    # The sign of a product of +-1 factors is the parity of the negative
+    # count: exact, and a sum where a product would need reduce_prod.
+    n_neg = reduce(jnp.where(p < 0, 1.0, 0.0))
+    sgnP = jnp.where((n_neg.astype(jnp.int32) & 1) == 1, -1.0, 1.0)
+    return S, logP, sgnP.astype(x.dtype)
 
 
 def combine(kid: int, S, logP, sgnP, dim: int):
     """Accumulators -> objective value (..., 1)."""
+    S0, S1 = S
     if kid == KID_SCHWEFEL:
-        return -S[..., 0:1] / dim
+        return -S0 / dim
     if kid == KID_RASTRIGIN:
-        return 10.0 * dim + S[..., 0:1]
+        return 10.0 * dim + S0
     if kid == KID_ACKLEY:
-        return (-20.0 * jnp.exp(-0.2 * jnp.sqrt(S[..., 0:1] / dim))
-                - jnp.exp(S[..., 1:2] / dim) + 20.0 + _E)
+        return (-20.0 * jnp.exp(-0.2 * jnp.sqrt(S0 / dim))
+                - jnp.exp(S1 / dim) + 20.0 + _E)
     if kid == KID_GRIEWANK:
         P = sgnP * jnp.exp(logP)
-        return 1.0 + S[..., 0:1] - P
+        return 1.0 + S0 - P
     if kid == KID_EXPONENTIAL:
-        return -jnp.exp(-0.5 * S[..., 0:1])
+        return -jnp.exp(-0.5 * S0)
     if kid == KID_SALOMON:
-        r = jnp.sqrt(S[..., 0:1])
+        r = jnp.sqrt(S0)
         return 1.0 - jnp.cos(2 * _PI * r) + 0.1 * r
     raise ValueError(f"unknown kernel objective id {kid}")
 
@@ -169,25 +218,25 @@ def full_eval_rt(kid, x, dim: int):
     return f
 
 
+def _select(kid, k, new, old):
+    """``jnp.where(kid == k, new, old)`` leaf by leaf over a pytree."""
+    return jax.tree.map(lambda a, b: jnp.where(kid == k, a, b), new, old)
+
+
 def term_rt(kid, xi, d):
-    """Runtime-kid term; kid broadcastable to (..., 1)."""
-    s, p = term(0, xi, d)
+    """Runtime-kid term; kid broadcastable to xi."""
+    out = term(0, xi, d)
     for k in range(1, N_KIDS):
-        sk, pk = term(k, xi, d)
-        s = jnp.where(kid == k, sk, s)
-        p = jnp.where(kid == k, pk, p)
-    return s, p
+        out = _select(kid, k, term(k, xi, d), out)
+    return out
 
 
-def init_acc_rt(kid, x):
+def init_acc_rt(kid, x, reduce=lane_sum):
     """Runtime-kid init_acc; kid broadcastable to (..., 1)."""
-    S, logP, sgnP = init_acc(0, x)
+    out = init_acc(0, x, reduce)
     for k in range(1, N_KIDS):
-        Sk, logPk, sgnPk = init_acc(k, x)
-        S = jnp.where(kid == k, Sk, S)
-        logP = jnp.where(kid == k, logPk, logP)
-        sgnP = jnp.where(kid == k, sgnPk, sgnP)
-    return S, logP, sgnP
+        out = _select(kid, k, init_acc(k, x, reduce), out)
+    return out
 
 
 def combine_rt(kid, S, logP, sgnP, dim: int):

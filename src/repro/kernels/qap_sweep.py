@@ -32,10 +32,10 @@ oracle (`ref.qap_sweep_ref`, built on the same shared step math) matches
 the Pallas lowering bitwise — the property the serving engine's
 bit-exactness oracle stands on (tests/test_qap.py).
 
-Gathers are expressed as one-hot matmuls (sums of a single non-zero term
-— exact regardless of order), the Mosaic-friendly formulation; ``n`` is
-tiny (<= a few dozen), so the (blk, n, n) one-hots live comfortably in
-VMEM.
+Gathers are one-hot row selects (``(1, n) @ (n, n)`` products) and
+static loops over the ``n`` locations on 2-D ``(blk, n)`` tiles: sums of a
+single non-zero term, exact regardless of order.  No rank-3 one-hot is
+built — the Pallas TPU compiler aborts on one.
 """
 from __future__ import annotations
 
@@ -51,21 +51,59 @@ from repro.kernels import rng
 from repro.kernels.metropolis_sweep import _per_block
 
 
+def _onehot(v, n: int):
+    """``(B, 1)`` int column -> ``(B, n)`` float32 one-hot rows."""
+    return (lax.broadcasted_iota(v.dtype, (v.shape[0], n), 1) == v
+            ).astype(jnp.float32)
+
+
+def _row(M, v):
+    """One-hot row select: ``_row(M, onehot(i))[b, k] = M[i, k]``.
+
+    ``v`` is ``(B, n)``.  A shared ``(n, n)`` operand (the kernel's) takes
+    one ``(B, n) @ (n, n)`` matmul; a per-chain ``(B, n, n)`` operand (the
+    oracle's mixed-instance batches) one ``(1, n) @ (n, n)`` product per
+    chain.  Either way each entry is a sum of one non-zero term, exact in
+    any order and at any matmul precision for the small-integer instances.
+    """
+    if M.ndim == 2:
+        return v @ M
+    return (v[:, None, :] @ M)[:, 0, :]
+
+
+def _gather(R, p):
+    """``_gather(R, p)[b, k] = R[b, p[b, k]]`` for ``(B, n)`` rows ``R``.
+
+    A static loop over the ``n`` locations on 2-D ``(B, n)`` tiles (a
+    rank-3 ``(B, n, n)`` one-hot aborts the Pallas TPU compiler): each
+    output element picks one term and adds exact zeros.
+    """
+    n = p.shape[-1]
+    out = jnp.zeros(p.shape, jnp.float32)
+    for loc in range(n):
+        out = out + jnp.where(p == loc, R[:, loc:loc + 1], 0.0)
+    return out
+
+
 def qap_full_cost(p, F, D):
     """Full QAP cost ``sum_{u,v} F[u,v] * D[p[u],p[v]]`` per chain.
 
     Args:
       p: (B, n) int32 permutations.
       F, D: (n, n) — or (B, n, n) per-chain — float32 integer-valued
-        matrices (broadcasting batched matmuls either way).
+        matrices.
 
     Returns (B, 1) float32 costs — exact for integer data below 2**24.
     """
     n = p.shape[-1]
-    locs = jnp.arange(n, dtype=p.dtype)
-    P = (p[..., None] == locs).astype(jnp.float32)        # (B, n, n) one-hot
-    DP = (P @ D) @ jnp.swapaxes(P, -1, -2)                # D[p[u], p[v]]
-    return jnp.sum(F * DP, axis=(-2, -1))[..., None]
+    cost = jnp.zeros((p.shape[0], 1), jnp.float32)
+    for u in range(n):
+        # Row u of D[p, p]: D[p[u], :] through the one-hot row select,
+        # then its columns gathered at p.
+        dp_u = _gather(_row(D, _onehot(p[:, u:u + 1], n)), p)
+        f_u = F[u:u + 1, :] if F.ndim == 2 else F[:, u, :]
+        cost = cost + jnp.sum(f_u * dp_u, axis=-1, keepdims=True)
+    return cost
 
 
 def qap_swap_sweep(p, fx, F, D, T, seed, cidx, step0, *, n_steps: int,
@@ -103,10 +141,6 @@ def qap_swap_sweep(p, fx, F, D, T, seed, cidx, step0, *, n_steps: int,
     n = p.shape[-1]
     locs = jnp.arange(n, dtype=p.dtype)
 
-    def row(M, v):
-        """One-hot row select: ``row(M, onehot(i))[k] = M[i, k]``."""
-        return (v[:, None, :] @ M)[:, 0, :]
-
     def body(s, carry):
         p, fx = carry
         rbits, uval, uacc = rng.draws3(seed, cidx,
@@ -124,23 +158,17 @@ def qap_swap_sweep(p, fx, F, D, T, seed, cidx, step0, *, n_steps: int,
 
         FT = jnp.swapaxes(F, -1, -2)
         DT = jnp.swapaxes(D, -1, -2)
-        Fi, Fj = row(F, eif), row(F, ejf)          # F[i,:], F[j,:]
-        FiT, FjT = row(FT, eif), row(FT, ejf)      # F[:,i], F[:,j]
-        Da, Db = row(D, laf), row(D, lbf)          # D[a,:], D[b,:]
-        DaT, DbT = row(DT, laf), row(DT, lbf)      # D[:,a], D[:,b]
-
-        # Gathers at p[k] via the permutation one-hot (exact sums of one
-        # non-zero term): g(R)[k] = R[p[k]].
-        P = (p[..., None] == locs).astype(jnp.float32)    # (B, n, n)
-
-        def g(R):
-            return (P @ R[..., None])[..., 0]
+        Fi, Fj = _row(F, eif), _row(F, ejf)        # F[i,:], F[j,:]
+        FiT, FjT = _row(FT, eif), _row(FT, ejf)    # F[:,i], F[:,j]
+        Da, Db = _row(D, laf), _row(D, lbf)        # D[a,:], D[b,:]
+        DaT, DbT = _row(DT, laf), _row(DT, lbf)    # D[:,a], D[:,b]
 
         kmask = (1.0 - eif) * (1.0 - ejf)                 # k not in {i, j}
-        t1 = jnp.sum((Fi - Fj) * (g(Db) - g(Da)) * kmask,
+        # Rows gathered at p[k]: _gather(R, p)[k] = R[p[k]].
+        t1 = jnp.sum((Fi - Fj) * (_gather(Db, p) - _gather(Da, p)) * kmask,
                      axis=-1, keepdims=True)
-        t2 = jnp.sum((FiT - FjT) * (g(DbT) - g(DaT)) * kmask,
-                     axis=-1, keepdims=True)
+        t2 = jnp.sum((FiT - FjT) * (_gather(DbT, p) - _gather(DaT, p))
+                     * kmask, axis=-1, keepdims=True)
 
         def pick(R, v):
             return jnp.sum(R * v, axis=-1, keepdims=True)
@@ -167,7 +195,6 @@ def _qap_kernel(T_ref, seed_ref, step0_ref, base_ref, live_ref,
                 blk: int):
     """One grid step: sweep one (blk, n) block on its own instance."""
     pid = pl.program_id(0)
-    n = p_ref.shape[-1]
     T = T_ref[pid]
     seed = seed_ref[pid]
     step0 = step0_ref[pid]
@@ -175,12 +202,11 @@ def _qap_kernel(T_ref, seed_ref, step0_ref, base_ref, live_ref,
     cidx = (base_ref[pid]
             + lax.broadcasted_iota(jnp.int32, (blk, 1), 0).astype(jnp.uint32))
     p = p_ref[...]
-    F = F_ref[...]
+    F = F_ref[...]                  # (n, n): the squeezed instance block
     D = D_ref[...]
     # Initial cost from scratch — exact (integer-valued f32), so the carry
     # that leaves this kernel bitwise equals a host full evaluation.
     fx = qap_full_cost(p, F, D)
-    del n
     p, fx = qap_swap_sweep(p, fx, F, D, T, seed, cidx, step0,
                            n_steps=n_steps, live=live)
     po_ref[...] = p
@@ -223,7 +249,9 @@ def qap_sweep_pallas(p, F_blocks, D_blocks, T, seed, step0, *,
             raise ValueError(
                 f"{name} must be (n, n) or (n_blocks*n, n) = "
                 f"({n_blocks * n}, {n}); got {M.shape}")
-        return M
+        # (n_blocks, n, n) with a squeezed leading block dim: the block's
+        # last two dims then equal the array's, as the TPU lowering needs.
+        return M.reshape(n_blocks, n, n)
 
     Fb = pack(F_blocks, "F_blocks")
     Db = pack(D_blocks, "D_blocks")
@@ -245,8 +273,8 @@ def qap_sweep_pallas(p, F_blocks, D_blocks, T, seed, step0, *,
         in_specs=(
             [pl.BlockSpec(memory_space=pltpu.SMEM)] * 5
             + [pl.BlockSpec((blk, n), lambda i: (i, 0)),
-               pl.BlockSpec((n, n), lambda i: (i, 0)),
-               pl.BlockSpec((n, n), lambda i: (i, 0))]),
+               pl.BlockSpec((None, n, n), lambda i: (i, 0, 0)),
+               pl.BlockSpec((None, n, n), lambda i: (i, 0, 0))]),
         out_specs=[
             pl.BlockSpec((blk, n), lambda i: (i, 0)),
             pl.BlockSpec((blk, 1), lambda i: (i, 0)),

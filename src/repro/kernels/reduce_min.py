@@ -21,15 +21,21 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 
+#: Each block writes its (min, argmin) broadcast over one whole (8, 128)
+#: f32 tile: the TPU lowering needs output blocks whose last two dims
+#: divide by (8, 128), so a (1, 1) block per grid step cannot lower.
+_TILE = (8, 128)
+
+
 def _argmin_kernel(f_ref, m_ref, i_ref, *, blk: int):
     pid = pl.program_id(0)
     f = f_ref[...]                                    # (1, blk)
     idx = lax.broadcasted_iota(jnp.int32, (1, blk), 1)
-    m = jnp.min(f)
+    m = jnp.min(f, axis=1, keepdims=True)             # (1, 1)
     # first index attaining the block minimum
-    i = jnp.min(jnp.where(f == m, idx, blk))
-    m_ref[0, 0] = m
-    i_ref[0, 0] = pid * blk + i
+    i = jnp.min(jnp.where(f == m, idx, blk), axis=1, keepdims=True)
+    m_ref[...] = jnp.broadcast_to(m, _TILE)
+    i_ref[...] = jnp.broadcast_to(pid * blk + i, _TILE)
 
 
 def block_argmin_pallas(f, *, blk: int = 1024, interpret: bool = False):
@@ -42,18 +48,19 @@ def block_argmin_pallas(f, *, blk: int = 1024, interpret: bool = False):
     if n % blk:
         raise ValueError(f"n={n} must be a multiple of blk={blk}")
     grid = (n // blk,)
+    rows, lanes = _TILE
+    tile = pl.BlockSpec(_TILE, lambda i: (i, 0))
     mins, idxs = pl.pallas_call(
         functools.partial(_argmin_kernel, blk=blk),
         grid=grid,
         in_specs=[pl.BlockSpec((1, blk), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((1, 1), lambda i: (0, i)),
-                   pl.BlockSpec((1, 1), lambda i: (0, i))],
-        out_shape=[jax.ShapeDtypeStruct((1, grid[0]), f.dtype),
-                   jax.ShapeDtypeStruct((1, grid[0]), jnp.int32)],
+        out_specs=[tile, tile],
+        out_shape=[jax.ShapeDtypeStruct((grid[0] * rows, lanes), f.dtype),
+                   jax.ShapeDtypeStruct((grid[0] * rows, lanes), jnp.int32)],
         interpret=interpret,
         name="block_argmin",
     )(f.reshape(1, n))
-    return mins[0], idxs[0]
+    return mins[::rows, 0], idxs[::rows, 0]
 
 
 def argmin_reduce(f, *, blk: int = 1024, use_pallas: bool = False,

@@ -1,11 +1,13 @@
 """Pure-jnp oracle for the Metropolis-sweep kernel.
 
-Computes the *identical* floating-point recurrence as the Pallas kernel
-(same RNG counters via ``rng.draws3``, same accumulator math via
-``objective_math``), vectorized over all chains at once with no blocking.
-Because the RNG is counter-based on the global chain index, the kernel's
+Runs the Pallas kernel's own sweep body (``metropolis_sweep.sweep_chains``:
+same RNG counters, same accumulator math) vectorized over all chains at
+once with no blocking, as plain XLA.  Only the accumulator init's lane
+sum differs (``objective_math.lane_sum_tree``: XLA:CPU rounds a fused
+row reduction differently from one program shape to another).  Because
+the RNG is counter-based on the global chain index, the kernel's
 chain-block decomposition does not change random streams, so kernel and
-oracle must agree to float tolerance.
+oracle agree to float tolerance.
 
 For the multi-tenant serving engine the control inputs generalize from
 scalars to per-chain arrays: ``kid``, ``T``, ``seed`` and ``step0`` may each
@@ -30,8 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.kernels import metropolis_sweep as ms
 from repro.kernels import objective_math as om
-from repro.kernels import rng
 
 
 def _col(v, chains: int, dtype):
@@ -44,8 +46,7 @@ def _col(v, chains: int, dtype):
 
 def metropolis_sweep_ref(x, T, seed, step0, *, kid, n_steps: int,
                          variant: str = "delta", cidx=None, live=None):
-    from repro.kernels.metropolis_sweep import _validate_kid
-    _validate_kid(kid)
+    ms._validate_kid(kid)
     # Concrete scalar kid -> single-branch specialization (1x objective
     # math, one jit cache entry per objective — the pre-runtime behavior);
     # array/traced kid -> runtime jnp.where dispatch, one entry total.
@@ -63,8 +64,8 @@ def _metropolis_sweep_ref_static(x, T, seed, step0, *, kid: int,
                                  cidx=None, live=None):
     lo, hi = om.BOX[kid]
     return _sweep_ref_body(x, T, seed, step0, kid, np.float32(lo),
-                           np.float32(hi), om.init_acc, om.combine, om.term,
-                           om.full_eval, n_steps, variant, cidx, live)
+                           np.float32(hi), ms.STATIC_FNS, n_steps, variant,
+                           cidx, live)
 
 
 @partial(jax.jit, static_argnames=("n_steps", "variant"))
@@ -72,79 +73,26 @@ def _metropolis_sweep_ref(x, T, seed, step0, *, kid, n_steps: int,
                           variant: str = "delta", cidx=None, live=None):
     kid = _col(kid, x.shape[0], jnp.int32)
     lo, hi = om.box_rt(kid, dtype=x.dtype)  # (chains, 1) box bounds
-    return _sweep_ref_body(x, T, seed, step0, kid, lo, hi, om.init_acc_rt,
-                           om.combine_rt, om.term_rt, om.full_eval_rt,
+    return _sweep_ref_body(x, T, seed, step0, kid, lo, hi, ms.RUNTIME_FNS,
                            n_steps, variant, cidx, live)
 
 
-def _sweep_ref_body(x, T, seed, step0, kid, lo, hi, init_acc, combine, term,
-                    full_eval, n_steps, variant, cidx, live=None):
-    chains, dim = x.shape
+def _sweep_ref_body(x, T, seed, step0, kid, lo, hi, fns, n_steps, variant,
+                    cidx, live=None):
+    chains = x.shape[0]
     if cidx is None:
         cidx = jnp.arange(chains, dtype=jnp.uint32)[:, None]  # (chains, 1)
     else:
         cidx = _col(cidx, chains, jnp.uint32)
-    coords = jnp.broadcast_to(jnp.arange(dim, dtype=jnp.int32), (chains, dim))
-    seed = _col(seed, chains, jnp.uint32)
-    step0 = _col(step0, chains, jnp.uint32)
-    T = _col(T, chains, x.dtype)
     # Per-chain level cursor (macro-tick serving): a dead chain's accepts
     # are all masked off so its state passes through bit-exactly — the
     # oracle-side mirror of the kernel's per-block ``live`` SMEM input.
     live = None if live is None else _col(live, chains, jnp.bool_)
-
-    if variant == "delta":
-        S, logP, sgnP = init_acc(kid, x)
-        fx = combine(kid, S, logP, sgnP, dim)
-
-        def body(i, carry):
-            x, fx, S, logP, sgnP = carry
-            rbits, uval, uacc = rng.draws3(seed, cidx, (step0 + i).astype(jnp.uint32))
-            d = (rbits % np.uint32(dim)).astype(jnp.int32)
-            onehot = coords == d
-            xi_old = jnp.sum(jnp.where(onehot, x, 0.0), axis=1, keepdims=True)
-            newval = lo + uval * (hi - lo)
-            df = d.astype(x.dtype)
-            s_old, p_old = term(kid, xi_old, df)
-            s_new, p_new = term(kid, newval, df)
-            S1 = S - s_old + s_new
-            logP1 = (logP
-                     - jnp.log(jnp.maximum(jnp.abs(p_old), 1e-30))
-                     + jnp.log(jnp.maximum(jnp.abs(p_new), 1e-30)))
-            sg = jnp.where(p_old < 0, -1.0, 1.0) * jnp.where(p_new < 0, -1.0, 1.0)
-            sgnP1 = sgnP * sg.astype(sgnP.dtype)
-            f1 = combine(kid, S1, logP1, sgnP1, dim)
-            acc = uacc <= jnp.exp(jnp.clip(-(f1 - fx) / T, -80.0, 80.0))
-            if live is not None:
-                acc = acc & live
-            x = jnp.where(onehot & acc, newval, x)
-            fx = jnp.where(acc, f1, fx)
-            S = jnp.where(acc, S1, S)
-            logP = jnp.where(acc, logP1, logP)
-            sgnP = jnp.where(acc, sgnP1, sgnP)
-            return x, fx, S, logP, sgnP
-
-        x, fx, *_ = lax.fori_loop(0, n_steps, body, (x, fx, S, logP, sgnP))
-    else:
-        fx = full_eval(kid, x, dim)
-
-        def body(i, carry):
-            x, fx = carry
-            rbits, uval, uacc = rng.draws3(seed, cidx, (step0 + i).astype(jnp.uint32))
-            d = (rbits % np.uint32(dim)).astype(jnp.int32)
-            onehot = coords == d
-            newval = lo + uval * (hi - lo)
-            x1 = jnp.where(onehot, newval, x)
-            f1 = full_eval(kid, x1, dim)
-            acc = uacc <= jnp.exp(jnp.clip(-(f1 - fx) / T, -80.0, 80.0))
-            if live is not None:
-                acc = acc & live
-            x = jnp.where(acc, x1, x)
-            fx = jnp.where(acc, f1, fx)
-            return x, fx
-
-        x, fx = lax.fori_loop(0, n_steps, body, (x, fx))
-
+    x, fx = ms.sweep_chains(
+        x, _col(T, chains, x.dtype), _col(seed, chains, jnp.uint32), cidx,
+        _col(step0, chains, jnp.uint32), kid=kid, lo=lo, hi=hi, fns=fns,
+        n_steps=n_steps, variant=variant, live=live,
+        reduce=om.lane_sum_tree)
     return x, fx[:, 0]
 
 

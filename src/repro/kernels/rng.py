@@ -43,8 +43,14 @@ def threefry2x32(k0, k1, x0, x1):
 
 
 def uniform_from_bits(bits):
-    """uint32 -> float32 uniform in [0, 1) with 24-bit mantissa usage."""
-    return (bits >> np.uint32(8)).astype(jnp.float32) * np.float32(1.0 / (1 << 24))
+    """uint32 -> float32 uniform in [0, 1) with 24-bit mantissa usage.
+
+    The cast goes through int32 (the Pallas TPU lowering has no
+    uint32 -> float32 cast); ``bits >> 8`` is below 2**24, so both casts
+    are exact and the result is unchanged.
+    """
+    top24 = (bits >> np.uint32(8)).astype(jnp.int32)
+    return top24.astype(jnp.float32) * np.float32(1.0 / (1 << 24))
 
 
 def draws3(seed, chain_idx, step):

@@ -24,30 +24,9 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
     """Arbitrary mesh for tests/examples (e.g. (1,1) on CPU)."""
-    try:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        # Older jax: no jax.sharding.AxisType / axis_types kwarg (Auto is
-        # that jax's only behaviour anyway) — build the mesh without it.
-        return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def shard_map(fn, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` with an older-jax fallback.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=...)``; older
-    releases only have ``jax.experimental.shard_map.shard_map`` whose
-    equivalent knob is spelled ``check_rep``.  Every shard_map call in
-    this repo goes through here so multi-device code runs on both.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def slot_pool_mesh(n_shards: int):

@@ -306,8 +306,7 @@ def _moe(mp, h, cfg: ModelConfig, mesh):
         fn = partial(L.moe_apply, top_k=cfg.top_k,
                      capacity_factor=cfg.capacity_factor,
                      ep_axis="model", ep_size=ep)
-        from repro.launch.mesh import shard_map
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=mesh,
             in_specs=({"router": P(), "w_gate": P("model"), "w_up": P("model"),
                        "w_down": P("model")}, P(dp)),

@@ -453,6 +453,12 @@ class SAServeEngine:
         #: submit tick).
         self._submit_info: Dict[int, Tuple[float, float]] = {}
 
+    @property
+    def use_pallas(self) -> bool:
+        """Whether sweeps run the Pallas kernels (``cfg.use_pallas``
+        resolved; ``'auto'`` means on a TPU backend only)."""
+        return self._use_pallas
+
     def _now(self) -> float:
         """Wall seconds since engine construction (the engine epoch).
 

@@ -29,6 +29,7 @@ import json
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.service.arrivals import ArrivalProcess, latency_summary
 from repro.service.engine import (EngineConfig, SAServeEngine, run_standalone)
 from repro.service.request import SARequest
@@ -221,6 +222,25 @@ def make_mix(n_requests: int, chains_per_slot: int, seed: int = 0,
                 min_levels=max(1, int(min_levels_frac * req.n_levels)))
         reqs.append(req)
     return reqs
+
+
+def standalone_replay(req: SARequest, res, cfg: EngineConfig):
+    """``req`` served alone, replaying what the packed run did to it.
+
+    A degraded admission is bit-exact vs a standalone run at the
+    *admitted* chain count (same logical chain indices and RNG); a job
+    shrunk mid-flight (drain / proactive degrade) vs a standalone run
+    that replays the same width schedule on the level axis, and a
+    ladder-truncated job vs one that replays the same truncation
+    schedule (cuts move only the ladder's end, so champions are
+    prefix-exact).  ``res`` is the packed run's RequestResult.
+    """
+    solo_req = req if res.admitted_chains >= req.n_chains else \
+        dataclasses.replace(req, n_chains=res.admitted_chains)
+    sched = [(lvl, to) for lvl, _frm, to in res.shrink_events]
+    cuts = [(lvl, to) for lvl, _frm, to in res.truncate_events]
+    return run_standalone(solo_req, cfg, shrink_schedule=sched,
+                          truncate_schedule=cuts)
 
 
 def make_arrivals(reqs, kind: str, rate: float, seed: int,
@@ -513,19 +533,7 @@ def main(argv=None):
     if args.check:
         for req in served:
             res = by_id[req.req_id]
-            # A degraded admission is bit-exact vs a standalone run at the
-            # *admitted* chain count (same logical chain indices and RNG);
-            # a job shrunk mid-flight (drain / proactive degrade) is
-            # bit-exact vs a standalone run that replays the same width
-            # schedule on the level axis, and a ladder-truncated job vs
-            # one that replays the same truncation schedule (cuts move
-            # only the ladder's end, so champions are prefix-exact).
-            solo_req = req if res.admitted_chains >= req.n_chains else \
-                dataclasses.replace(req, n_chains=res.admitted_chains)
-            sched = [(lvl, to) for lvl, _frm, to in res.shrink_events]
-            cuts = [(lvl, to) for lvl, _frm, to in res.truncate_events]
-            solo = run_standalone(solo_req, cfg, shrink_schedule=sched,
-                                  truncate_schedule=cuts)
+            solo = standalone_replay(req, res, cfg)
             if res.f_best == solo.f_best:
                 n_exact += 1
             else:
@@ -672,4 +680,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

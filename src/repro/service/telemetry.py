@@ -415,7 +415,7 @@ class EventLog:
 
 
 # ---------------------------------------------------------- jax compile hook
-_COMPILE_EVENTS = {"count": 0}
+_COMPILE_EVENTS = {"count": 0, "seconds": 0.0}
 _HOOK_INSTALLED = False
 
 #: jax.monitoring duration-event key emitted once per backend (XLA)
@@ -428,17 +428,15 @@ def _install_compile_hook() -> None:
     global _HOOK_INSTALLED
     if _HOOK_INSTALLED:
         return
-    try:
-        import jax.monitoring as _mon
+    import jax.monitoring as _mon
 
-        def _listener(name, secs, **kw):
-            if name == _BACKEND_COMPILE_EVENT:
-                _COMPILE_EVENTS["count"] += 1
+    def _listener(name, secs, **kw):
+        if name == _BACKEND_COMPILE_EVENT:
+            _COMPILE_EVENTS["count"] += 1
+            _COMPILE_EVENTS["seconds"] += secs
 
-        _mon.register_event_duration_secs_listener(_listener)
-        _HOOK_INSTALLED = True
-    except Exception:        # pragma: no cover - very old jax: counter stays 0
-        pass
+    _mon.register_event_duration_secs_listener(_listener)
+    _HOOK_INSTALLED = True
 
 
 def compile_events() -> int:
@@ -450,6 +448,13 @@ def compile_events() -> int:
     """
     _install_compile_hook()
     return _COMPILE_EVENTS["count"]
+
+
+def compile_seconds() -> float:
+    """Process-wide wall seconds spent in XLA backend compilations so far
+    (the same hook as :func:`compile_events`)."""
+    _install_compile_hook()
+    return _COMPILE_EVENTS["seconds"]
 
 
 # ------------------------------------------------------------------- facade

@@ -68,7 +68,7 @@ import json, jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.distributed.compression import compressed_psum
-from repro.launch.mesh import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((8,), ("data",))
 g = jnp.asarray(np.random.default_rng(0).normal(size=(8, 32)).astype(np.float32))
 
@@ -76,8 +76,9 @@ def body(gl):
     s, resid = compressed_psum(gl, ("data",))
     return s, resid
 
-f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data"),
-                      out_specs=(P("data"), P("data"))))
+f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                          out_specs=(P("data"), P("data")),
+                          check_vma=False))
 s, resid = f(g)
 exact = jnp.sum(g, axis=0)
 rel = float(jnp.max(jnp.abs(s[0] - exact)) / (jnp.max(jnp.abs(exact)) + 1e-9))

@@ -44,8 +44,7 @@ def local_loss(rp, x):
 
 def ep_loss(rp, x):
     fn = partial(L.moe_apply, **kw, ep_axis="model", ep_size=8)
-    from repro.launch.mesh import shard_map
-    y = shard_map(fn, mesh=mesh,
+    y = jax.shard_map(fn, mesh=mesh,
                   in_specs=({"router": P(), "w_gate": P("model"),
                              "w_up": P("model"), "w_down": P("model")},
                             P()),

@@ -21,6 +21,8 @@ re-runs the CLI smoke with 4 real XLA host devices.
 
 import json
 
+import jax
+import numpy as np
 import pytest
 
 from repro.service import (
@@ -39,7 +41,8 @@ from repro.service import (
     validate_trace,
 )
 from repro.service.engine import _group_tick
-from repro.service.telemetry import Histogram, MetricsRegistry
+from repro.service.telemetry import (Histogram, MetricsRegistry,
+                                     compile_seconds)
 
 CPS = 8
 
@@ -93,6 +96,14 @@ def test_disabled_allocates_no_spans_and_compiles_nothing_extra():
     before = compile_events()
     _serve(Telemetry(trace=TraceBuilder(), events=EventLog()))
     assert compile_events() - before <= compile_disabled
+
+
+def test_compile_hook_counts_events_and_seconds():
+    """One fresh program is one backend compile, and its seconds add up."""
+    events, secs = compile_events(), compile_seconds()
+    jax.jit(lambda a: a * 3.0 + 1.0)(np.arange(7, dtype=np.float32))
+    assert compile_events() - events == 1
+    assert compile_seconds() > secs
 
 
 def test_enabled_compiles_no_extra_group_programs():
