@@ -284,23 +284,31 @@ def serving_exchange(x, fx, seg, num_segments, adopt, mcode, t_rung, T_exch,
     inside a fused macro-tick.
 
     Returns (x, fx, xb, fb) like :func:`exchange_sync_segmented`.
+
+    Each stage runs under a ``jax.named_scope`` (``champion``, ``adopt``,
+    ``pt_swap``, ``pa_resample``), so a profiler trace times the stages
+    apart; scopes are metadata and change no operation.
     """
     n = fx.shape[0]
-    xb, fb, ib = segment_champion(x, fx, seg, num_segments)
-    valid = (ib < n)[seg] & live
+    with jax.named_scope("champion"):
+        xb, fb, ib = segment_champion(x, fx, seg, num_segments)
+        valid = (ib < n)[seg] & live
 
-    is_sos = mcode == MCODE_SOS
-    u_sos = exchange_uniform(seed_c, SOS_SALT, cidx, lvl_abs)
-    sos_take = is_sos & (u_sos <= sos_adopt_prob(fx, fb[seg], T_exch))
-    take = valid & (adopt | sos_take)
-    x = jnp.where(take[:, None], xb[seg], x)
-    fx = jnp.where(take, fb[seg], fx)
+    with jax.named_scope("adopt"):
+        is_sos = mcode == MCODE_SOS
+        u_sos = exchange_uniform(seed_c, SOS_SALT, cidx, lvl_abs)
+        sos_take = is_sos & (u_sos <= sos_adopt_prob(fx, fb[seg], T_exch))
+        take = valid & (adopt | sos_take)
+        x = jnp.where(take[:, None], xb[seg], x)
+        fx = jnp.where(take, fb[seg], fx)
 
-    x, fx = pt_swap_segmented(x, fx, t_rung, partner, pairlo, seed_c,
-                              lvl_abs, (mcode == MCODE_PT) & live)
-    x, fx = pa_resample_segmented(x, fx, fb, seg, seg_lo, seg_hi, dbeta_c,
-                                  seed_c, cidx, lvl_abs,
-                                  (mcode == MCODE_PA) & live)
+    with jax.named_scope("pt_swap"):
+        x, fx = pt_swap_segmented(x, fx, t_rung, partner, pairlo, seed_c,
+                                  lvl_abs, (mcode == MCODE_PT) & live)
+    with jax.named_scope("pa_resample"):
+        x, fx = pa_resample_segmented(x, fx, fb, seg, seg_lo, seg_hi,
+                                      dbeta_c, seed_c, cidx, lvl_abs,
+                                      (mcode == MCODE_PA) & live)
     return x, fx, xb, fb
 
 

@@ -70,7 +70,8 @@ from repro.service.scheduler import (AdmissionPlan, AdmissionScheduler,
 from repro.service.sharding import EngineShard, slot_pool_devices
 from repro.service.slots import ActiveJob, SlotPool, SwappedJob
 from repro.service.telemetry import (EventLog, MetricsRegistry, PhaseTimer,
-                                     Telemetry, TICK_PHASES, compile_events)
+                                     SubPhaseTimer, Telemetry, TICK_PHASES,
+                                     TICK_SUBPHASES, compile_events)
 from repro.service.trace import TraceBuilder, validate_trace
 
 __all__ = [
@@ -83,6 +84,7 @@ __all__ = [
     "EngineShard", "slot_pool_devices",
     "ArrivalProcess", "latency_summary",
     "Autoscaler", "AutoscalerConfig",
-    "Telemetry", "MetricsRegistry", "PhaseTimer", "EventLog",
-    "TICK_PHASES", "compile_events", "TraceBuilder", "validate_trace",
+    "Telemetry", "MetricsRegistry", "PhaseTimer", "SubPhaseTimer",
+    "EventLog", "TICK_PHASES", "TICK_SUBPHASES", "compile_events",
+    "TraceBuilder", "validate_trace",
 ]

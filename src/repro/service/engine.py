@@ -191,18 +191,26 @@ def _group_tick(x, kid_blk, T_blk, seed_blk, step0_blk, base_blk, lvl0_blk,
     plain-SA-only batch is bitwise the classic path.  The champion is
     returned for every segment either way so the host can fold
     best-so-far.
+
+    The three stages run under ``jax.named_scope``s — ``sa.controls``,
+    ``sa.sweep``, ``sa.exchange`` — that name their device ops in a
+    profiler trace (metadata only: the program is the same).  Every
+    group program uses the same three.
     """
-    sched, T_chain, seed_c, cidx, lvl_abs = _chain_controls(
-        T_blk, seed_blk, base_blk, lvl0_blk, mcode, t_rung, blk)
-    x, fx = ops.metropolis_sweep_slots(
-        x, kid_blk, T_blk, seed_blk, step0_blk, base_blk, n_steps=n_steps,
-        blk=blk, variant=variant, use_pallas=use_pallas, interpret=interpret,
-        T_chain=T_chain)
+    with jax.named_scope("sa.controls"):
+        sched, T_chain, seed_c, cidx, lvl_abs = _chain_controls(
+            T_blk, seed_blk, base_blk, lvl0_blk, mcode, t_rung, blk)
+        dbeta_c = jnp.repeat(dbeta_blk, blk)
+    with jax.named_scope("sa.sweep"):
+        x, fx = ops.metropolis_sweep_slots(
+            x, kid_blk, T_blk, seed_blk, step0_blk, base_blk,
+            n_steps=n_steps, blk=blk, variant=variant, use_pallas=use_pallas,
+            interpret=interpret, T_chain=T_chain)
     live = jnp.ones(fx.shape, bool)
-    return exch.serving_exchange(
-        x, fx, seg, num_segments, adopt, mcode, t_rung, sched, partner,
-        pairlo, seg_lo, seg_hi, jnp.repeat(dbeta_blk, blk), seed_c, cidx,
-        lvl_abs, live)
+    with jax.named_scope("sa.exchange"):
+        return exch.serving_exchange(
+            x, fx, seg, num_segments, adopt, mcode, t_rung, sched, partner,
+            pairlo, seg_lo, seg_hi, dbeta_c, seed_c, cidx, lvl_abs, live)
 
 
 @partial(jax.jit, static_argnames=("k", "n_steps", "blk", "variant",
@@ -250,23 +258,30 @@ def _group_tick_fused(x, kid_blk, T_lvls, seed_blk, step0_blk, base_blk,
     def body(i, carry):
         x, fx_keep, fb_all, xb_all = carry
         live = i < levels_blk                       # (n_blocks,) cursor
-        T_i = lax.dynamic_index_in_dim(T_lvls, i, 0, keepdims=False)
-        db_i = lax.dynamic_index_in_dim(dbeta_lvls, i, 0, keepdims=False)
-        step0_i = step0_blk + jnp.uint32(n_steps) * i.astype(jnp.uint32)
-        sched, T_chain, seed_c, cidx, lvl_abs = _chain_controls(
-            T_i, seed_blk, base_blk, lvl0_blk + i.astype(jnp.uint32),
-            mcode, t_rung, blk)
-        x, fx = ops.metropolis_sweep_slots(
-            x, kid_blk, T_i, seed_blk, step0_i, base_blk, n_steps=n_steps,
-            blk=blk, variant=variant, use_pallas=use_pallas,
-            interpret=interpret, live=live, T_chain=T_chain)
-        live_c = jnp.repeat(live, blk)
-        prt = lax.dynamic_index_in_dim(partner2, i % 2, 0, keepdims=False)
-        plo = lax.dynamic_index_in_dim(pairlo2, i % 2, 0, keepdims=False)
-        x, fx, xb, fb = exch.serving_exchange(
-            x, fx, seg, num_segments, adopt, mcode, t_rung, sched, prt,
-            plo, seg_lo, seg_hi, jnp.repeat(db_i, blk), seed_c, cidx,
-            lvl_abs, live_c)
+        with jax.named_scope("sa.controls"):
+            T_i = lax.dynamic_index_in_dim(T_lvls, i, 0, keepdims=False)
+            db_i = lax.dynamic_index_in_dim(dbeta_lvls, i, 0,
+                                            keepdims=False)
+            step0_i = step0_blk + jnp.uint32(n_steps) * i.astype(jnp.uint32)
+            sched, T_chain, seed_c, cidx, lvl_abs = _chain_controls(
+                T_i, seed_blk, base_blk, lvl0_blk + i.astype(jnp.uint32),
+                mcode, t_rung, blk)
+            live_c = jnp.repeat(live, blk)
+            prt = lax.dynamic_index_in_dim(partner2, i % 2, 0,
+                                           keepdims=False)
+            plo = lax.dynamic_index_in_dim(pairlo2, i % 2, 0,
+                                           keepdims=False)
+            dbeta_c = jnp.repeat(db_i, blk)
+        with jax.named_scope("sa.sweep"):
+            x, fx = ops.metropolis_sweep_slots(
+                x, kid_blk, T_i, seed_blk, step0_i, base_blk,
+                n_steps=n_steps, blk=blk, variant=variant,
+                use_pallas=use_pallas, interpret=interpret, live=live,
+                T_chain=T_chain)
+        with jax.named_scope("sa.exchange"):
+            x, fx, xb, fb = exch.serving_exchange(
+                x, fx, seg, num_segments, adopt, mcode, t_rung, sched, prt,
+                plo, seg_lo, seg_hi, dbeta_c, seed_c, cidx, lvl_abs, live_c)
         fx_keep = jnp.where(live_c, fx, fx_keep)
         return x, fx_keep, fb_all.at[i].set(fb), xb_all.at[i].set(xb)
 
@@ -296,16 +311,20 @@ def _group_tick_qap(x, F_blk, D_blk, T_blk, seed_blk, step0_blk, base_blk,
     PA reweighting increment is identically zero.  A separate jit (typed
     on int32 x) naturally pins one compiled program per family.
     """
-    sched, T_chain, seed_c, cidx, lvl_abs = _chain_controls(
-        T_blk, seed_blk, base_blk, lvl0_blk, mcode, t_rung, blk)
-    x, fx = ops.qap_sweep_slots(
-        x, F_blk, D_blk, T_blk, seed_blk, step0_blk, base_blk,
-        n_steps=n_steps, blk=blk, use_pallas=use_pallas, interpret=interpret)
+    with jax.named_scope("sa.controls"):
+        sched, T_chain, seed_c, cidx, lvl_abs = _chain_controls(
+            T_blk, seed_blk, base_blk, lvl0_blk, mcode, t_rung, blk)
+    with jax.named_scope("sa.sweep"):
+        x, fx = ops.qap_sweep_slots(
+            x, F_blk, D_blk, T_blk, seed_blk, step0_blk, base_blk,
+            n_steps=n_steps, blk=blk, use_pallas=use_pallas,
+            interpret=interpret)
     live = jnp.ones(fx.shape, bool)
-    return exch.serving_exchange(
-        x, fx, seg, num_segments, adopt, mcode, t_rung, sched, partner,
-        pairlo, seg_lo, seg_hi, jnp.zeros_like(fx), seed_c, cidx,
-        lvl_abs, live)
+    with jax.named_scope("sa.exchange"):
+        return exch.serving_exchange(
+            x, fx, seg, num_segments, adopt, mcode, t_rung, sched, partner,
+            pairlo, seg_lo, seg_hi, jnp.zeros_like(fx), seed_c, cidx,
+            lvl_abs, live)
 
 
 @partial(jax.jit, static_argnames=("k", "n_steps", "blk", "use_pallas",
@@ -332,22 +351,27 @@ def _group_tick_qap_fused(x, F_blk, D_blk, T_lvls, seed_blk, step0_blk,
     def body(i, carry):
         x, fx_keep, fb_all, xb_all = carry
         live = i < levels_blk                       # (n_blocks,) cursor
-        T_i = lax.dynamic_index_in_dim(T_lvls, i, 0, keepdims=False)
-        step0_i = step0_blk + jnp.uint32(n_steps) * i.astype(jnp.uint32)
-        sched, T_chain, seed_c, cidx, lvl_abs = _chain_controls(
-            T_i, seed_blk, base_blk, lvl0_blk + i.astype(jnp.uint32),
-            mcode, t_rung, blk)
-        x, fx = ops.qap_sweep_slots(
-            x, F_blk, D_blk, T_i, seed_blk, step0_i, base_blk,
-            n_steps=n_steps, blk=blk, use_pallas=use_pallas,
-            interpret=interpret, live=live)
-        live_c = jnp.repeat(live, blk)
-        prt = lax.dynamic_index_in_dim(partner2, i % 2, 0, keepdims=False)
-        plo = lax.dynamic_index_in_dim(pairlo2, i % 2, 0, keepdims=False)
-        x, fx, xb, fb = exch.serving_exchange(
-            x, fx, seg, num_segments, adopt, mcode, t_rung, sched, prt,
-            plo, seg_lo, seg_hi, jnp.zeros_like(fx), seed_c, cidx,
-            lvl_abs, live_c)
+        with jax.named_scope("sa.controls"):
+            T_i = lax.dynamic_index_in_dim(T_lvls, i, 0, keepdims=False)
+            step0_i = step0_blk + jnp.uint32(n_steps) * i.astype(jnp.uint32)
+            sched, T_chain, seed_c, cidx, lvl_abs = _chain_controls(
+                T_i, seed_blk, base_blk, lvl0_blk + i.astype(jnp.uint32),
+                mcode, t_rung, blk)
+            live_c = jnp.repeat(live, blk)
+            prt = lax.dynamic_index_in_dim(partner2, i % 2, 0,
+                                           keepdims=False)
+            plo = lax.dynamic_index_in_dim(pairlo2, i % 2, 0,
+                                           keepdims=False)
+        with jax.named_scope("sa.sweep"):
+            x, fx = ops.qap_sweep_slots(
+                x, F_blk, D_blk, T_i, seed_blk, step0_i, base_blk,
+                n_steps=n_steps, blk=blk, use_pallas=use_pallas,
+                interpret=interpret, live=live)
+        with jax.named_scope("sa.exchange"):
+            x, fx, xb, fb = exch.serving_exchange(
+                x, fx, seg, num_segments, adopt, mcode, t_rung, sched, prt,
+                plo, seg_lo, seg_hi, jnp.zeros_like(fx), seed_c, cidx,
+                lvl_abs, live_c)
         fx_keep = jnp.where(live_c, fx, fx_keep)
         return x, fx_keep, fb_all.at[i].set(fb), xb_all.at[i].set(xb)
 
@@ -443,9 +467,11 @@ class SAServeEngine:
                 f"chains_per_slot={cfg.chains_per_slot} must be a multiple "
                 "of 8 (TPU sublanes) on the Pallas path")
         self._epoch = time.perf_counter()
-        # Phase spans share the engine's monotonic epoch; the NULL
-        # telemetry hands back a shared no-op timer (zero allocation).
+        # Phase spans and the sub-spans under them share the engine's
+        # monotonic epoch; the NULL telemetry hands back one shared no-op
+        # timer for both (zero allocation).
         self._pt = self.telemetry.make_phase_timer(self._now)
+        self._sub = self.telemetry.make_subphase_timer(self._now)
         if self.telemetry.trace is not None:
             self.telemetry.trace.bind_clock(self._now)
         #: req_id -> (arrival_time in ticks, submit wall time): lifecycle
@@ -647,7 +673,9 @@ class SAServeEngine:
             job = entry.swapped.job
             job.resumed_ticks.append(self.tick_count)
             shard.rids.alloc(job)
-            job.slots = shard.pool.restore(job.rid, entry.swapped.blocks)
+            with self._sub("admit.restore"):
+                job.slots = shard.pool.restore(job.rid,
+                                               entry.swapped.blocks)
             job.home_shard = shard.index
             if tel.enabled:
                 tel.decision(self.tick_count, "resume",
@@ -670,7 +698,9 @@ class SAServeEngine:
                         home_shard=shard.index,
                         levels_limit=req.n_levels)
         shard.rids.alloc(job)
-        job.slots = shard.pool.assign(job.rid, req, n_slots=granted_slots)
+        with self._sub("admit.init_state"):
+            job.slots = shard.pool.assign(job.rid, req,
+                                          n_slots=granted_slots)
         job.granted_chains = granted_slots * self.cfg.chains_per_slot
         if tel.enabled:
             tel.decision(self.tick_count, "admit", req_id=req.req_id,
@@ -688,7 +718,8 @@ class SAServeEngine:
         its slots, and re-queue it for a bit-exact resume (on whichever
         shard next has room — swap-in doubles as migration)."""
         job = shard.rids.jobs[rid]
-        blocks = shard.pool.checkpoint(rid)
+        with self._sub("admit.restore"):
+            blocks = shard.pool.checkpoint(rid)
         shard.pool.release(rid)
         shard.rids.free(rid)
         job.slots = []
@@ -713,11 +744,12 @@ class SAServeEngine:
         job keeps annealing this tick (on its new device); the trajectory
         is bit-exact because restore is placement-invariant."""
         job = src.rids.jobs[rid]
-        blocks = src.pool.checkpoint(rid)
-        src.pool.release(rid)
-        src.rids.free(rid)
-        dst.rids.alloc(job)
-        job.slots = dst.pool.restore(job.rid, blocks)
+        with self._sub("admit.restore"):
+            blocks = src.pool.checkpoint(rid)
+            src.pool.release(rid)
+            src.rids.free(rid)
+            dst.rids.alloc(job)
+            job.slots = dst.pool.restore(job.rid, blocks)
         job.home_shard = dst.index
         job.migrated_ticks.append(self.tick_count)
         self.migrations += 1
@@ -829,9 +861,10 @@ class SAServeEngine:
                 f"keep_slots must be in [1, {len(job.slots) - 1}], "
                 f"got {keep_slots}")
         from_chains = job.granted_chains
-        blocks = shard.pool.checkpoint(rid)[:keep_slots]
-        shard.pool.release(rid)
-        job.slots = shard.pool.restore(rid, blocks)
+        with self._sub("admit.restore"):
+            blocks = shard.pool.checkpoint(rid)[:keep_slots]
+            shard.pool.release(rid)
+            job.slots = shard.pool.restore(rid, blocks)
         self._record_shrink(job, from_chains, self_driven=self_driven)
 
     def _shrink_migrate(self, src: EngineShard, rid: int, dst: EngineShard,
@@ -840,11 +873,12 @@ class SAServeEngine:
         restore only the first ``keep_slots`` blocks on ``dst``."""
         job = src.rids.jobs[rid]
         from_chains = job.granted_chains
-        blocks = src.pool.checkpoint(rid)[:keep_slots]
-        src.pool.release(rid)
-        src.rids.free(rid)
-        dst.rids.alloc(job)
-        job.slots = dst.pool.restore(job.rid, blocks)
+        with self._sub("admit.restore"):
+            blocks = src.pool.checkpoint(rid)[:keep_slots]
+            src.pool.release(rid)
+            src.rids.free(rid)
+            dst.rids.alloc(job)
+            job.slots = dst.pool.restore(job.rid, blocks)
         job.home_shard = dst.index
         job.migrated_ticks.append(self.tick_count)
         self.migrations += 1
@@ -1188,23 +1222,14 @@ class SAServeEngine:
         self.tick_count += advance
 
     def _end_tick_telemetry(self, levels: int = 1) -> None:
-        """Drain this tick's spans into the registry / trace (no-op when
-        telemetry is off — the null timer drains empty).  ``levels`` is
-        the ladder-level advance of this tick (K for an active macro-tick)
-        so the tick counter metric stays on the level clock."""
-        tel = self.telemetry
-        if not tel.enabled:
-            return
-        acc, shard_acc, raw, cpu = self._pt.drain()
-        for (shard_idx, phase), secs in shard_acc.items():
-            shard = next((s for s in self.shards if s.index == shard_idx),
-                         None)
-            if shard is not None:
-                shard.phase_seconds[phase] = \
-                    shard.phase_seconds.get(phase, 0.0) + secs
-        tel.end_tick(self.tick_count, acc, shard_acc, raw, self.shards,
-                     len(self.scheduler), self.n_active, levels=levels,
-                     cpu=cpu)
+        """Drain this tick's spans and sub-spans into the registry / trace
+        (no-op when telemetry is off).  ``levels`` is the ladder-level
+        advance of this tick (K for an active macro-tick) so the tick
+        counter metric stays on the level clock."""
+        if self.telemetry.enabled:
+            self.telemetry.end_tick(self.tick_count, self._pt, self._sub,
+                                    self.shards, len(self.scheduler),
+                                    self.n_active, levels=levels)
 
     def _collect_group(self, shard: EngineShard, n_steps: int,
                        jobs: List[ActiveJob], slot_list, outs):
@@ -1215,39 +1240,46 @@ class SAServeEngine:
         equivalent)."""
         cps = self.cfg.chains_per_slot
         tel = self.telemetry
-        x2, xb, fb = (np.asarray(outs[0]), np.asarray(outs[2]),
-                      np.asarray(outs[3]))
-        fxh = (np.asarray(outs[1])
-               if any(j.req.pa_ess_ratio > 0 for j in jobs) else None)
-        for b, (s, job) in enumerate(slot_list):
-            # Copy: a bare slice would alias (and pin) the whole padded buffer.
-            shard.pool.set_block(s, x2[b * cps:(b + 1) * cps].copy())
+        sub = self._sub
+        with sub("materialize.d2h", shard.index):
+            x2, xb, fb = (np.asarray(outs[0]), np.asarray(outs[2]),
+                          np.asarray(outs[3]))
+            fxh = (np.asarray(outs[1])
+                   if any(j.req.pa_ess_ratio > 0 for j in jobs) else None)
+        if tel.enabled:
+            tel.m_state_bytes.inc(x2.nbytes, "d2h")
+        with sub("materialize.scatter", shard.index):
+            for b, (s, job) in enumerate(slot_list):
+                # Copy: a bare slice would alias (and pin) the whole
+                # padded buffer.
+                shard.pool.set_block(s, x2[b * cps:(b + 1) * cps].copy())
         finished = []
         row0 = 0
-        for job in jobs:
-            rows = slice(row0, row0 + job.granted_chains)
-            row0 += job.granted_chains
-            f = float(fb[job.rid])
-            if f < job.best_f:
-                job.best_f = f
-                job.best_x = xb[job.rid].copy()
-            if job.first_tick < 0:
-                job.first_tick = self.tick_count
-                job.first_tick_wall = self._now()
-            self.sweeps_done += len(job.slots)
-            shard.sweeps_done += len(job.slots)
-            job.level += 1
-            job.steps_done += n_steps
-            job.evals += n_steps * job.granted_chains
-            job.T *= job.req.rho
-            job.history.append(job.best_f)       # champion trajectory/level
-            if tel.enabled:
-                tel.tenant_slot_ticks(job.req.req_id, len(job.slots))
-            reason = self._finish_reason(job)
-            if reason is not None:
-                finished.append((shard, job, reason, self.tick_count))
-            elif fxh is not None:
-                self._maybe_pa_shrink(shard, job, fxh[rows])
+        with sub("materialize.fold", shard.index):
+            for job in jobs:
+                rows = slice(row0, row0 + job.granted_chains)
+                row0 += job.granted_chains
+                f = float(fb[job.rid])
+                if f < job.best_f:
+                    job.best_f = f
+                    job.best_x = xb[job.rid].copy()
+                if job.first_tick < 0:
+                    job.first_tick = self.tick_count
+                    job.first_tick_wall = self._now()
+                self.sweeps_done += len(job.slots)
+                shard.sweeps_done += len(job.slots)
+                job.level += 1
+                job.steps_done += n_steps
+                job.evals += n_steps * job.granted_chains
+                job.T *= job.req.rho
+                job.history.append(job.best_f)   # champion trajectory/level
+                if tel.enabled:
+                    tel.tenant_slot_ticks(job.req.req_id, len(job.slots))
+                reason = self._finish_reason(job)
+                if reason is not None:
+                    finished.append((shard, job, reason, self.tick_count))
+                elif fxh is not None:
+                    self._maybe_pa_shrink(shard, job, fxh[rows])
         return finished
 
     def _collect_group_fused(self, shard: EngineShard, n_steps: int,
@@ -1275,44 +1307,48 @@ class SAServeEngine:
         """
         tel = self.telemetry
         boundary = self.tick_count
-        fb_all = np.asarray(outs[2])    # (K, num_segments) champion values
-        xb_all = np.asarray(outs[3])    # (K, num_segments, dim) champions
-        fxh = (np.asarray(outs[1])      # last-live-level post-exchange fx
-               if any(j.req.pa_ess_ratio > 0 for j in jobs) else None)
+        with self._sub("materialize.d2h", shard.index):
+            fb_all = np.asarray(outs[2])  # (K, num_segments) champion values
+            xb_all = np.asarray(outs[3])  # (K, num_segments, dim) champions
+            fxh = (np.asarray(outs[1])    # last-live-level post-exchange fx
+                   if any(j.req.pa_ess_ratio > 0 for j in jobs) else None)
         finished = []
         max_counted = 1
         row0 = 0
-        for job in jobs:
-            rows = slice(row0, row0 + job.granted_chains)
-            row0 += job.granted_chains
-            if job.first_tick < 0:
-                job.first_tick = boundary
-                job.first_tick_wall = self._now()
-            counted = 0
-            reason = None
-            for i in range(planned[job.rid]):
-                f = float(fb_all[i, job.rid])
-                if f < job.best_f:
-                    job.best_f = f
-                    job.best_x = xb_all[i, job.rid].copy()
-                counted += 1
-                self.sweeps_done += len(job.slots)
-                shard.sweeps_done += len(job.slots)
-                job.level += 1
-                job.steps_done += n_steps
-                job.evals += n_steps * job.granted_chains
-                job.T *= job.req.rho
-                job.history.append(job.best_f)   # champion trajectory/level
-                if tel.enabled:
-                    tel.tenant_slot_ticks(job.req.req_id, len(job.slots))
-                reason = self._finish_reason(job)
+        with self._sub("materialize.fold", shard.index):
+            for job in jobs:
+                rows = slice(row0, row0 + job.granted_chains)
+                row0 += job.granted_chains
+                if job.first_tick < 0:
+                    job.first_tick = boundary
+                    job.first_tick_wall = self._now()
+                counted = 0
+                reason = None
+                for i in range(planned[job.rid]):
+                    f = float(fb_all[i, job.rid])
+                    if f < job.best_f:
+                        job.best_f = f
+                        job.best_x = xb_all[i, job.rid].copy()
+                    counted += 1
+                    self.sweeps_done += len(job.slots)
+                    shard.sweeps_done += len(job.slots)
+                    job.level += 1
+                    job.steps_done += n_steps
+                    job.evals += n_steps * job.granted_chains
+                    job.T *= job.req.rho
+                    job.history.append(job.best_f)   # champion per level
+                    if tel.enabled:
+                        tel.tenant_slot_ticks(job.req.req_id,
+                                              len(job.slots))
+                    reason = self._finish_reason(job)
+                    if reason is not None:
+                        break
+                max_counted = max(max_counted, counted)
                 if reason is not None:
-                    break
-            max_counted = max(max_counted, counted)
-            if reason is not None:
-                finished.append((shard, job, reason, boundary + counted - 1))
-            elif fxh is not None:
-                self._maybe_pa_shrink(shard, job, fxh[rows])
+                    finished.append((shard, job, reason,
+                                     boundary + counted - 1))
+                elif fxh is not None:
+                    self._maybe_pa_shrink(shard, job, fxh[rows])
         return finished, max_counted
 
     def _pack_class_controls(self, jobs: List[ActiveJob], n_padded: int,
@@ -1386,6 +1422,8 @@ class SAServeEngine:
         cps = self.cfg.chains_per_slot
         K = self.cfg.macro_k
         is_qap = family == fam_mod.FAMILY_PERMUTATION
+        tel = self.telemetry
+        sub = self._sub
         slot_list: List[Tuple[int, ActiveJob]] = [
             (s, job) for job in jobs for s in job.slots]
         n_blocks = len(slot_list)
@@ -1393,116 +1431,141 @@ class SAServeEngine:
         while n_padded < n_blocks:
             n_padded *= 2
 
-        planned: Dict[int, int] = {}
-        for job in jobs:
-            p = min(K, max(1, self._levels_limit(job) - job.level))
-            if job.req.max_evals is not None:
-                per_level = max(1, n_steps * job.granted_chains)
-                remaining = job.req.max_evals - job.evals
-                p = min(p, max(1, -(-remaining // per_level)))
-            planned[job.rid] = p
+        with sub("dispatch.pack", shard.index):
+            planned: Dict[int, int] = {}
+            for job in jobs:
+                p = min(K, max(1, self._levels_limit(job) - job.level))
+                if job.req.max_evals is not None:
+                    per_level = max(1, n_steps * job.granted_chains)
+                    remaining = job.req.max_evals - job.evals
+                    p = min(p, max(1, -(-remaining // per_level)))
+                planned[job.rid] = p
 
-        kid_blk = np.empty((n_padded,), np.int32)
-        if is_qap:
-            # Per-block instance operands, packed (n_padded * dim, dim):
-            # block b reads rows [b*dim, (b+1)*dim).  Runtime inputs, so
-            # mixed instances co-batch without recompiling.
-            F_blk = np.empty((n_padded * dim, dim), np.float32)
-            D_blk = np.empty((n_padded * dim, dim), np.float32)
-        T_lvls = np.empty((K, n_padded), np.float32)
-        dbeta_lvls = np.zeros((K, n_padded), np.float32)
-        seed_blk = np.empty((n_padded,), np.uint32)
-        step0_blk = np.empty((n_padded,), np.uint32)
-        base_blk = np.empty((n_padded,), np.uint32)
-        levels_blk = np.empty((n_padded,), np.int32)
-        lvl0_blk = np.zeros((n_padded,), np.uint32)
-        seg = np.empty((n_padded * cps,), np.int32)
-        adopt = np.empty((n_padded * cps,), bool)
-        for b, (s, job) in enumerate(slot_list):
-            kid_blk[b] = np.int32(job.req.kid)
+            kid_blk = np.empty((n_padded,), np.int32)
             if is_qap:
-                inst = job.req.instance
-                F_blk[b * dim:(b + 1) * dim] = inst.F
-                D_blk[b * dim:(b + 1) * dim] = inst.D
-            is_pa = job.req.method == "pa"
-            t = job.T
-            for i in range(K):
-                # float64 iteration, f32 per level — identical to K=1's
-                # pack-then-advance of the float ``job.T`` cursor.
-                T_lvls[i, b] = t
-                if is_pa:
-                    dbeta_lvls[i, b] = _pa_dbeta(t, job.req.rho)
-                t *= job.req.rho
-            seed_blk[b] = np.uint32(job.req.seed)
-            step0_blk[b] = np.uint32(job.steps_done)
-            base_blk[b] = shard.pool.chain_base[s]
-            levels_blk[b] = planned[job.rid]
-            lvl0_blk[b] = np.uint32(job.level)
-            seg[b * cps:(b + 1) * cps] = job.rid
-            adopt[b * cps:(b + 1) * cps] = (job.req.method == "sa"
-                                            and job.req.exchange == "sync")
-        for b in range(n_blocks, n_padded):
-            # Pad blocks are *dead* (zero planned levels): pure
-            # pass-through, so whatever a reused buffer holds in its pad
-            # rows is legal — they cost lanes, not correctness.
-            kid_blk[b] = kid_blk[0]
-            if is_qap:
-                F_blk[b * dim:(b + 1) * dim] = F_blk[:dim]
-                D_blk[b * dim:(b + 1) * dim] = D_blk[:dim]
-            T_lvls[:, b] = T_lvls[:, 0]
-            seed_blk[b] = seed_blk[0]
-            step0_blk[b] = step0_blk[0]
-            base_blk[b] = base_blk[0]
-            levels_blk[b] = 0
-            seg[b * cps:(b + 1) * cps] = self.cfg.n_slots
-            adopt[b * cps:(b + 1) * cps] = False
-        mcode, t_rung, partner2, pairlo2, seg_lo, seg_hi = \
-            self._pack_class_controls(jobs, n_padded, 2)
+                # Per-block instance operands, packed (n_padded * dim,
+                # dim): block b reads rows [b*dim, (b+1)*dim).  Runtime
+                # inputs, so mixed instances co-batch without recompiling.
+                F_blk = np.empty((n_padded * dim, dim), np.float32)
+                D_blk = np.empty((n_padded * dim, dim), np.float32)
+            T_lvls = np.empty((K, n_padded), np.float32)
+            dbeta_lvls = np.zeros((K, n_padded), np.float32)
+            seed_blk = np.empty((n_padded,), np.uint32)
+            step0_blk = np.empty((n_padded,), np.uint32)
+            base_blk = np.empty((n_padded,), np.uint32)
+            levels_blk = np.empty((n_padded,), np.int32)
+            lvl0_blk = np.zeros((n_padded,), np.uint32)
+            seg = np.empty((n_padded * cps,), np.int32)
+            adopt = np.empty((n_padded * cps,), bool)
+            for b, (s, job) in enumerate(slot_list):
+                kid_blk[b] = np.int32(job.req.kid)
+                if is_qap:
+                    inst = job.req.instance
+                    F_blk[b * dim:(b + 1) * dim] = inst.F
+                    D_blk[b * dim:(b + 1) * dim] = inst.D
+                is_pa = job.req.method == "pa"
+                t = job.T
+                for i in range(K):
+                    # float64 iteration, f32 per level — identical to
+                    # K=1's pack-then-advance of the float ``job.T`` cursor.
+                    T_lvls[i, b] = t
+                    if is_pa:
+                        dbeta_lvls[i, b] = _pa_dbeta(t, job.req.rho)
+                    t *= job.req.rho
+                seed_blk[b] = np.uint32(job.req.seed)
+                step0_blk[b] = np.uint32(job.steps_done)
+                base_blk[b] = shard.pool.chain_base[s]
+                levels_blk[b] = planned[job.rid]
+                lvl0_blk[b] = np.uint32(job.level)
+                seg[b * cps:(b + 1) * cps] = job.rid
+                adopt[b * cps:(b + 1) * cps] = (
+                    job.req.method == "sa" and job.req.exchange == "sync")
+            for b in range(n_blocks, n_padded):
+                # Pad blocks are *dead* (zero planned levels): pure
+                # pass-through, so whatever a reused buffer holds in its
+                # pad rows is legal — they cost lanes, not correctness.
+                kid_blk[b] = kid_blk[0]
+                if is_qap:
+                    F_blk[b * dim:(b + 1) * dim] = F_blk[:dim]
+                    D_blk[b * dim:(b + 1) * dim] = D_blk[:dim]
+                T_lvls[:, b] = T_lvls[:, 0]
+                seed_blk[b] = seed_blk[0]
+                step0_blk[b] = step0_blk[0]
+                base_blk[b] = base_blk[0]
+                levels_blk[b] = 0
+                seg[b * cps:(b + 1) * cps] = self.cfg.n_slots
+                adopt[b * cps:(b + 1) * cps] = False
+            mcode, t_rung, partner2, pairlo2, seg_lo, seg_hi = \
+                self._pack_class_controls(jobs, n_padded, 2)
+
+            cache = shard.group_cache.get((family, dim, n_steps))
+            x_dev = None
+            if cache is not None and cache["n_padded"] == n_padded:
+                buf = cache["buf"]
+                for b, (s, _job) in enumerate(slot_list):
+                    ref = shard.pool.device_ref(s)
+                    if (ref is None or ref.buf is not buf
+                            or ref.start != b * cps):
+                        break
+                else:
+                    x_dev = buf          # cache hit: skip repack + transfer
+            x = None
+            if x_dev is None:
+                x = np.empty((n_padded * cps, dim),
+                             np.int32 if is_qap else np.float32)
+                if tel.enabled:
+                    # Device-resident blocks come back to host for the
+                    # repack (get_block materializes them).
+                    n_refs = sum(shard.pool.device_ref(s) is not None
+                                 for s, _job in slot_list)
+                    tel.m_state_bytes.inc(n_refs * cps * dim * x.itemsize,
+                                          "d2h")
+                for b, (s, _job) in enumerate(slot_list):
+                    x[b * cps:(b + 1) * cps] = shard.pool.get_block(s)
+                for b in range(n_blocks, n_padded):
+                    x[b * cps:(b + 1) * cps] = x[:cps]
 
         dev = shard.device
-
-        cache = shard.group_cache.get((family, dim, n_steps))
-        x_dev = None
-        if cache is not None and cache["n_padded"] == n_padded:
-            buf = cache["buf"]
-            for b, (s, _job) in enumerate(slot_list):
-                ref = shard.pool.device_ref(s)
-                if ref is None or ref.buf is not buf or ref.start != b * cps:
-                    break
+        with sub("dispatch.h2d", shard.index):
+            if x is not None:
+                x_dev = jax.device_put(x, dev)
+            # One batched transfer for all control arrays: separate
+            # device_put dispatches were the dominant per-launch host cost
+            # once the state buffer started cache-hitting.
+            if is_qap:
+                ctrl = jax.device_put(
+                    (F_blk, D_blk, T_lvls, seed_blk, step0_blk, base_blk,
+                     levels_blk, lvl0_blk, seg, adopt, mcode, t_rung,
+                     partner2, pairlo2, seg_lo, seg_hi), dev)
             else:
-                x_dev = buf              # cache hit: skip repack + transfer
-        if x_dev is None:
-            x = np.empty((n_padded * cps, dim),
-                         np.int32 if is_qap else np.float32)
-            for b, (s, _job) in enumerate(slot_list):
-                x[b * cps:(b + 1) * cps] = shard.pool.get_block(s)
-            for b in range(n_blocks, n_padded):
-                x[b * cps:(b + 1) * cps] = x[:cps]
-            x_dev = jax.device_put(x, dev)
-
-        # One batched transfer for all control arrays: separate
-        # device_put dispatches were the dominant per-launch host cost
-        # once the state buffer started cache-hitting.
-        if is_qap:
-            ctrl = jax.device_put(
-                (F_blk, D_blk, T_lvls, seed_blk, step0_blk, base_blk,
-                 levels_blk, lvl0_blk, seg, adopt, mcode, t_rung, partner2,
-                 pairlo2, seg_lo, seg_hi), dev)
-            outs = _group_tick_qap_fused(
-                x_dev, *ctrl,
-                k=K, n_steps=n_steps, blk=cps,
-                use_pallas=self._use_pallas, interpret=self.cfg.interpret,
-                num_segments=self.cfg.n_slots + 1)
-        else:
-            ctrl = jax.device_put(
-                (kid_blk, T_lvls, seed_blk, step0_blk, base_blk, levels_blk,
-                 lvl0_blk, dbeta_lvls, seg, adopt, mcode, t_rung, partner2,
-                 pairlo2, seg_lo, seg_hi), dev)
-            outs = _group_tick_fused(
-                x_dev, *ctrl,
-                k=K, n_steps=n_steps, blk=cps, variant=self.cfg.variant,
-                use_pallas=self._use_pallas, interpret=self.cfg.interpret,
-                num_segments=self.cfg.n_slots + 1)
+                ctrl = jax.device_put(
+                    (kid_blk, T_lvls, seed_blk, step0_blk, base_blk,
+                     levels_blk, lvl0_blk, dbeta_lvls, seg, adopt, mcode,
+                     t_rung, partner2, pairlo2, seg_lo, seg_hi), dev)
+        with sub("dispatch.launch", shard.index):
+            if is_qap:
+                outs = _group_tick_qap_fused(
+                    x_dev, *ctrl,
+                    k=K, n_steps=n_steps, blk=cps,
+                    use_pallas=self._use_pallas,
+                    interpret=self.cfg.interpret,
+                    num_segments=self.cfg.n_slots + 1)
+            else:
+                outs = _group_tick_fused(
+                    x_dev, *ctrl,
+                    k=K, n_steps=n_steps, blk=cps, variant=self.cfg.variant,
+                    use_pallas=self._use_pallas,
+                    interpret=self.cfg.interpret,
+                    num_segments=self.cfg.n_slots + 1)
+        if tel.enabled:
+            tel.m_state_buffer.inc(1, "repack" if x is not None else "hit")
+            if x is not None:
+                tel.m_state_bytes.inc(x.nbytes, "h2d")
+            live = sum(planned[job.rid] * len(job.slots) for job in jobs)
+            tel.m_block_steps.inc(live * n_steps, "live")
+            tel.m_block_steps.inc((n_blocks * K - live) * n_steps, "dead")
+            tel.m_block_steps.inc((n_padded - n_blocks) * K * n_steps,
+                                  "padded")
         out_x = outs[0]
         # The group's state now lives in the output buffer.  Point every
         # slot there (lazily — materialized only by checkpoint/migrate/
@@ -1533,81 +1596,88 @@ class SAServeEngine:
         while n_padded < n_blocks:
             n_padded *= 2
 
-        x = np.empty((n_padded * cps, dim),
-                     np.int32 if is_qap else np.float32)
-        kid_blk = np.empty((n_padded,), np.int32)
-        if is_qap:
-            F_blk = np.empty((n_padded * dim, dim), np.float32)
-            D_blk = np.empty((n_padded * dim, dim), np.float32)
-        T_blk = np.empty((n_padded,), np.float32)
-        dbeta_blk = np.zeros((n_padded,), np.float32)
-        seed_blk = np.empty((n_padded,), np.uint32)
-        step0_blk = np.empty((n_padded,), np.uint32)
-        base_blk = np.empty((n_padded,), np.uint32)
-        lvl0_blk = np.zeros((n_padded,), np.uint32)
-        seg = np.empty((n_padded * cps,), np.int32)
-        adopt = np.empty((n_padded * cps,), bool)
-        for b, (s, job) in enumerate(slot_list):
-            x[b * cps:(b + 1) * cps] = shard.pool.get_block(s)
-            kid_blk[b] = np.int32(job.req.kid)
+        tel = self.telemetry
+        sub = self._sub
+        with sub("dispatch.pack", shard.index):
+            x = np.empty((n_padded * cps, dim),
+                         np.int32 if is_qap else np.float32)
+            kid_blk = np.empty((n_padded,), np.int32)
             if is_qap:
-                inst = job.req.instance
-                F_blk[b * dim:(b + 1) * dim] = inst.F
-                D_blk[b * dim:(b + 1) * dim] = inst.D
-            T_blk[b] = job.T
-            if job.req.method == "pa":
-                dbeta_blk[b] = _pa_dbeta(job.T, job.req.rho)
-            seed_blk[b] = np.uint32(job.req.seed)
-            step0_blk[b] = np.uint32(job.steps_done)
-            base_blk[b] = shard.pool.chain_base[s]
-            lvl0_blk[b] = np.uint32(job.level)
-            seg[b * cps:(b + 1) * cps] = job.rid
-            adopt[b * cps:(b + 1) * cps] = (job.req.method == "sa"
-                                            and job.req.exchange == "sync")
-        # Dummy pad blocks: replicate block 0, claim the reserved segment
-        # n_slots, never adopt. They cost lanes, not correctness.
-        for b in range(n_blocks, n_padded):
-            x[b * cps:(b + 1) * cps] = x[:cps]
-            kid_blk[b] = kid_blk[0]
-            if is_qap:
-                F_blk[b * dim:(b + 1) * dim] = F_blk[:dim]
-                D_blk[b * dim:(b + 1) * dim] = D_blk[:dim]
-            T_blk[b] = T_blk[0]
-            seed_blk[b] = seed_blk[0]
-            step0_blk[b] = step0_blk[0]
-            base_blk[b] = base_blk[0]
-            seg[b * cps:(b + 1) * cps] = self.cfg.n_slots
-            adopt[b * cps:(b + 1) * cps] = False
-        mcode, t_rung, partner, pairlo, seg_lo, seg_hi = \
-            self._pack_class_controls(jobs, n_padded, 1)
+                F_blk = np.empty((n_padded * dim, dim), np.float32)
+                D_blk = np.empty((n_padded * dim, dim), np.float32)
+            T_blk = np.empty((n_padded,), np.float32)
+            dbeta_blk = np.zeros((n_padded,), np.float32)
+            seed_blk = np.empty((n_padded,), np.uint32)
+            step0_blk = np.empty((n_padded,), np.uint32)
+            base_blk = np.empty((n_padded,), np.uint32)
+            lvl0_blk = np.zeros((n_padded,), np.uint32)
+            seg = np.empty((n_padded * cps,), np.int32)
+            adopt = np.empty((n_padded * cps,), bool)
+            for b, (s, job) in enumerate(slot_list):
+                x[b * cps:(b + 1) * cps] = shard.pool.get_block(s)
+                kid_blk[b] = np.int32(job.req.kid)
+                if is_qap:
+                    inst = job.req.instance
+                    F_blk[b * dim:(b + 1) * dim] = inst.F
+                    D_blk[b * dim:(b + 1) * dim] = inst.D
+                T_blk[b] = job.T
+                if job.req.method == "pa":
+                    dbeta_blk[b] = _pa_dbeta(job.T, job.req.rho)
+                seed_blk[b] = np.uint32(job.req.seed)
+                step0_blk[b] = np.uint32(job.steps_done)
+                base_blk[b] = shard.pool.chain_base[s]
+                lvl0_blk[b] = np.uint32(job.level)
+                seg[b * cps:(b + 1) * cps] = job.rid
+                adopt[b * cps:(b + 1) * cps] = (
+                    job.req.method == "sa" and job.req.exchange == "sync")
+            # Dummy pad blocks: replicate block 0, claim the reserved
+            # segment n_slots, never adopt. They cost lanes, not correctness.
+            for b in range(n_blocks, n_padded):
+                x[b * cps:(b + 1) * cps] = x[:cps]
+                kid_blk[b] = kid_blk[0]
+                if is_qap:
+                    F_blk[b * dim:(b + 1) * dim] = F_blk[:dim]
+                    D_blk[b * dim:(b + 1) * dim] = D_blk[:dim]
+                T_blk[b] = T_blk[0]
+                seed_blk[b] = seed_blk[0]
+                step0_blk[b] = step0_blk[0]
+                base_blk[b] = base_blk[0]
+                seg[b * cps:(b + 1) * cps] = self.cfg.n_slots
+                adopt[b * cps:(b + 1) * cps] = False
+            mcode, t_rung, partner, pairlo, seg_lo, seg_hi = \
+                self._pack_class_controls(jobs, n_padded, 1)
 
         # Committed transfers pin the group's program to the shard's mesh
         # device.  The call returns device arrays without blocking; the
         # collect pass materializes them after every shard has launched.
         dev = shard.device
-
-        def put(a):
-            return jax.device_put(a, dev)
-
-        if is_qap:
-            outs = _group_tick_qap(
-                put(x), put(F_blk), put(D_blk), put(T_blk), put(seed_blk),
-                put(step0_blk), put(base_blk), put(lvl0_blk), put(seg),
-                put(adopt), put(mcode), put(t_rung), put(partner[0]),
-                put(pairlo[0]), put(seg_lo), put(seg_hi), n_steps=n_steps,
-                blk=cps, use_pallas=self._use_pallas,
-                interpret=self.cfg.interpret,
-                num_segments=self.cfg.n_slots + 1)
-        else:
-            outs = _group_tick(
-                put(x), put(kid_blk), put(T_blk), put(seed_blk),
-                put(step0_blk), put(base_blk), put(lvl0_blk),
-                put(dbeta_blk), put(seg), put(adopt), put(mcode),
-                put(t_rung), put(partner[0]), put(pairlo[0]), put(seg_lo),
-                put(seg_hi), n_steps=n_steps, blk=cps,
-                variant=self.cfg.variant, use_pallas=self._use_pallas,
-                interpret=self.cfg.interpret,
-                num_segments=self.cfg.n_slots + 1)
+        with sub("dispatch.h2d", shard.index):
+            if is_qap:
+                args = (x, F_blk, D_blk, T_blk, seed_blk, step0_blk,
+                        base_blk, lvl0_blk, seg, adopt, mcode, t_rung,
+                        partner[0], pairlo[0], seg_lo, seg_hi)
+            else:
+                args = (x, kid_blk, T_blk, seed_blk, step0_blk, base_blk,
+                        lvl0_blk, dbeta_blk, seg, adopt, mcode, t_rung,
+                        partner[0], pairlo[0], seg_lo, seg_hi)
+            args = [jax.device_put(a, dev) for a in args]
+        with sub("dispatch.launch", shard.index):
+            if is_qap:
+                outs = _group_tick_qap(
+                    *args, n_steps=n_steps, blk=cps,
+                    use_pallas=self._use_pallas,
+                    interpret=self.cfg.interpret,
+                    num_segments=self.cfg.n_slots + 1)
+            else:
+                outs = _group_tick(
+                    *args, n_steps=n_steps, blk=cps,
+                    variant=self.cfg.variant, use_pallas=self._use_pallas,
+                    interpret=self.cfg.interpret,
+                    num_segments=self.cfg.n_slots + 1)
+        if tel.enabled:
+            tel.m_state_bytes.inc(x.nbytes, "h2d")
+            tel.m_block_steps.inc(n_blocks * n_steps, "live")
+            tel.m_block_steps.inc((n_padded - n_blocks) * n_steps, "padded")
         return shard, n_steps, jobs, slot_list, outs
 
     def _finish_reason(self, job: ActiveJob) -> Optional[str]:
@@ -1780,7 +1850,7 @@ class SAServeEngine:
             "sweeps_per_s": per_s(self.sweeps_done),
             "chain_steps_per_s": per_s(evals),
             # Cumulative per-phase wall seconds (empty unless telemetry
-            # was enabled): aggregate and per shard.
+            # was enabled): aggregate and per shard (retired shards too).
             "phases": self._phase_stats(),
         }
 
@@ -1790,9 +1860,13 @@ class SAServeEngine:
         hist = self.telemetry.m_tick_phase
         agg = {phase: hist.summary(phase)
                for (phase,) in sorted(hist.series)}
-        per_shard = {
-            str(s.index): dict(sorted(s.phase_seconds.items()))
-            for s in self.shards if s.phase_seconds}
+        # Every shard the fleet ever had, retired ones included: the
+        # counter's series are never pruned.
+        per_shard: Dict[str, Dict[str, float]] = {}
+        for (shard, phase), secs in sorted(
+                self.telemetry.m_shard_phase.series.items(),
+                key=lambda kv: (int(kv[0][0]), kv[0][1])):
+            per_shard.setdefault(shard, {})[phase] = secs
         cpu = {phase: secs for (phase,), secs
                in sorted(self.telemetry.m_phase_cpu.series.items())}
         return {"aggregate": agg, "per_shard": per_shard,

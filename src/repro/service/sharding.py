@@ -87,11 +87,6 @@ class EngineShard:
                                 # (utilization denominator — shards may
                                 # join/leave mid-run)
     draining: bool = False      # no new placements; evacuating to retire
-    phase_seconds: dict = dataclasses.field(default_factory=dict)
-                                # cumulative wall seconds per tick phase
-                                # (telemetry.py); empty when telemetry is
-                                # off — populated by the engine's
-                                # per-shard span folding
     group_cache: dict = dataclasses.field(default_factory=dict)
                                 # (family, dim, N) -> {"buf": device array,
                                 # "n_padded": int}: the fused macro-tick

@@ -17,9 +17,13 @@ GPU accounting, applied to a serving loop):
 * :class:`PhaseTimer` / :class:`NullPhaseTimer` — monotonic span
   accumulation for the engine tick's phases (``schedule / admit /
   dispatch / device_wait / materialize / retire``), per shard and
-  aggregate.  The null variant is a reusable no-op context manager:
-  telemetry off means **zero span objects allocated** per tick (tests
-  assert this via :attr:`PhaseTimer.spans_entered`).
+  aggregate, each also opened as a ``jax.profiler.TraceAnnotation``
+  (``sa.<phase>``) so the profiler's device trace sees it on its own
+  clock.  :class:`SubPhaseTimer` does the same one level down
+  (``sa.<phase>.<sub>``, :data:`TICK_SUBPHASES`).  The null variant is a
+  reusable no-op context manager shared by both: telemetry off means
+  **zero span objects allocated** per tick (tests assert this via
+  :attr:`PhaseTimer.spans_entered` and :attr:`SubPhaseTimer.spans_entered`).
 * :class:`EventLog` — seeded-deterministic one-line-JSON records of every
   scheduler/engine *decision* (admit, resume, preempt, migrate, shrink,
   reject, retire, drain, shard lifecycle).  Records carry tick-clock
@@ -44,6 +48,8 @@ import math
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from jax.profiler import TraceAnnotation
+
 #: The engine tick's phase taxonomy, in execution order (docs/observability.md):
 #:   schedule     — scheduler planning (placement, migration, shrink, admit plans)
 #:   admit        — executing the plans (checkpoint/restore, slot assignment)
@@ -53,6 +59,25 @@ from typing import Dict, List, Optional, Sequence, Tuple
 #:   retire       — finish checks, result records, slot release
 TICK_PHASES = ("schedule", "admit", "dispatch", "device_wait",
                "materialize", "retire")
+
+#: Spans one level under a phase, named ``<phase>.<sub>``
+#: (docs/observability.md):
+#:   admit.init_state     — a new request's initial chain states (sample_x0)
+#:   admit.restore        — checkpoint / restore copies of resident blocks
+#:   dispatch.pack        — the packed state and per-block controls on host
+#:   dispatch.h2d         — the device_put calls
+#:   dispatch.launch      — the jitted group program's call (the enqueue;
+#:                          a compile lands here)
+#:   materialize.d2h      — np.asarray reads of the program's outputs
+#:   materialize.scatter  — copying blocks back into their slots
+#:   materialize.fold     — champion fold, history and finish checks
+TICK_SUBPHASES = ("admit.init_state", "admit.restore", "dispatch.pack",
+                  "dispatch.h2d", "dispatch.launch", "materialize.d2h",
+                  "materialize.scatter", "materialize.fold")
+
+#: Profiler annotation names, built once: ``sa.<phase>`` and
+#: ``sa.<phase>.<sub>``.
+_ANNOTATION = {name: "sa." + name for name in TICK_PHASES + TICK_SUBPHASES}
 
 
 # --------------------------------------------------------------------- metrics
@@ -303,9 +328,10 @@ class PhaseTimer:
             ...
 
     Spans never nest (the tick's phases are sequential), so one instance
-    re-enters itself — no object allocation per span.  ``drain()`` returns
-    and resets the accumulated (aggregate, per-shard, raw span, host-CPU)
-    state; the engine folds it into histograms / trace events at tick end.
+    re-enters itself — no timer object allocated per span.  ``drain()``
+    returns and resets the accumulated (aggregate, per-shard, raw span,
+    host-CPU) state; :meth:`Telemetry.end_tick` folds it into histograms
+    / trace events at tick end.
 
     Each span records **two** clocks: monotonic wall time and the host
     thread's CPU time (``time.thread_time``).  On a host core dedicated to
@@ -314,6 +340,11 @@ class PhaseTimer:
     absorb whatever work the OS timesliced in, while thread-CPU counts
     only cycles the engine loop itself burned — the durable measure of
     host-side cost per phase.
+
+    Inside the timed span it also opens a profiler annotation
+    ``sa.<phase>`` (the profiler's clock, beside the device ops) and
+    counts the backend compiles that land in the span
+    (:func:`compile_events`, into ``compiles``).
     """
 
     #: Class-wide count of spans ever entered — the zero-overhead witness:
@@ -321,7 +352,7 @@ class PhaseTimer:
     spans_entered = 0
 
     __slots__ = ("_clock", "acc", "shard_acc", "raw", "cpu_acc", "keep_raw",
-                 "_phase", "_shard", "_t0", "_c0")
+                 "compiles", "_phase", "_shard", "_t0", "_c0", "_n0", "_ann")
 
     def __init__(self, clock, keep_raw: bool = False):
         self._clock = clock         # monotonic epoch-relative seconds
@@ -330,6 +361,7 @@ class PhaseTimer:
         self.shard_acc: Dict[Tuple[int, str], float] = {}
         self.raw: List[Tuple[str, Optional[int], float, float]] = []
         self.cpu_acc: Dict[str, float] = {}
+        self.compiles: Dict[str, int] = {}
 
     def __call__(self, phase: str, shard: Optional[int] = None):
         self._phase, self._shard = phase, shard
@@ -339,14 +371,20 @@ class PhaseTimer:
         PhaseTimer.spans_entered += 1
         self._t0 = self._clock()
         self._c0 = time.thread_time()
+        self._n0 = compile_events()
+        self._ann = TraceAnnotation(_ANNOTATION[self._phase])
         return self
 
     def __exit__(self, *exc):
+        self._ann.__exit__(None, None, None)
+        n = compile_events() - self._n0
         dc = time.thread_time() - self._c0
         t1 = self._clock()
         dt = t1 - self._t0
         self.acc[self._phase] = self.acc.get(self._phase, 0.0) + dt
         self.cpu_acc[self._phase] = self.cpu_acc.get(self._phase, 0.0) + dc
+        if n:
+            self.compiles[self._phase] = self.compiles.get(self._phase, 0) + n
         if self._shard is not None:
             key = (self._shard, self._phase)
             self.shard_acc[key] = self.shard_acc.get(key, 0.0) + dt
@@ -361,8 +399,60 @@ class PhaseTimer:
         return acc, shard_acc, raw, cpu
 
 
+class SubPhaseTimer:
+    """Spans one level under a phase (:data:`TICK_SUBPHASES`).
+
+    The same reusable context manager as :class:`PhaseTimer`, but
+    re-entrant: a sub-span may open while another is open (a population-
+    annealing shrink's ``admit.restore`` runs inside ``materialize.fold``),
+    so the open spans sit on a stack.  Each span's wall seconds go to
+    ``acc`` (never to the phase sums), its profiler annotation is
+    ``sa.<phase>.<sub>``, and with ``keep_raw`` its ``(name, shard, t0,
+    t1)`` goes to ``raw`` for the trace document.
+    """
+
+    #: Class-wide count of sub-spans ever entered (the same zero-overhead
+    #: witness as :attr:`PhaseTimer.spans_entered`).
+    spans_entered = 0
+
+    __slots__ = ("_clock", "keep_raw", "acc", "raw", "_name", "_shard",
+                 "_stack")
+
+    def __init__(self, clock, keep_raw: bool = False):
+        self._clock = clock
+        self.keep_raw = keep_raw
+        self.acc: Dict[str, float] = {}
+        self.raw: List[Tuple[str, Optional[int], float, float]] = []
+        self._stack: List[tuple] = []   # (name, shard, t0, annotation)
+
+    def __call__(self, name: str, shard: Optional[int] = None):
+        self._name, self._shard = name, shard
+        return self
+
+    def __enter__(self):
+        SubPhaseTimer.spans_entered += 1
+        self._stack.append((self._name, self._shard, self._clock(),
+                            TraceAnnotation(_ANNOTATION[self._name])))
+        return self
+
+    def __exit__(self, *exc):
+        name, shard, t0, ann = self._stack.pop()
+        ann.__exit__(None, None, None)
+        t1 = self._clock()
+        self.acc[name] = self.acc.get(name, 0.0) + (t1 - t0)
+        if self.keep_raw:
+            self.raw.append((name, shard, t0, t1))
+        return False
+
+    def drain(self):
+        acc, raw = self.acc, self.raw
+        self.acc, self.raw = {}, []
+        return acc, raw
+
+
 class NullPhaseTimer:
-    """No-op spans: one shared instance, no state, no allocation."""
+    """No-op spans: one shared instance, no state, no allocation.  The
+    null twin of both :class:`PhaseTimer` and :class:`SubPhaseTimer`."""
 
     __slots__ = ()
 
@@ -522,10 +612,34 @@ class Telemetry:
         self.m_plans = r.counter(
             "sa_scheduler_plans_total",
             "Actions planned per scheduler planner", ("plan",))
+        self.m_subphase = r.counter(
+            "sa_tick_subphase_seconds_total",
+            "Cumulative wall seconds per span under a tick phase "
+            "(<phase>.<sub>; not part of sa_tick_phase_seconds)", ("span",))
+        self.m_compiles = r.counter(
+            "sa_compiles_total",
+            "XLA backend compilations seen inside each tick phase",
+            ("phase",))
+        self.m_state_bytes = r.counter(
+            "sa_state_bytes_total",
+            "Chain-state bytes moved between host and device by the "
+            "tick's dispatch and materialize", ("direction",))
+        self.m_block_steps = r.counter(
+            "sa_block_steps_total",
+            "Block-steps launched (one slot block, one Metropolis step): "
+            "live, padded to a power of two, or dead in a macro-tick",
+            ("kind",))
+        self.m_state_buffer = r.counter(
+            "sa_state_buffer_total",
+            "Fused launches whose device state buffer was reused (hit) or "
+            "packed anew on host (repack)", ("result",))
 
     # -- hooks the engine calls (every one a no-op on NullTelemetry) --
     def make_phase_timer(self, clock) -> PhaseTimer:
         return PhaseTimer(clock, keep_raw=self.trace is not None)
+
+    def make_subphase_timer(self, clock) -> SubPhaseTimer:
+        return SubPhaseTimer(clock, keep_raw=self.trace is not None)
 
     def decision(self, tick: int, kind: str, **fields) -> None:
         """Record one scheduler/engine decision: counter + event record.
@@ -539,26 +653,35 @@ class Telemetry:
         """Scheduler hook: ``n_actions`` planned by planner ``kind``."""
         self.m_plans.inc(n_actions, kind)
 
-    def end_tick(self, tick: int, acc, shard_acc, raw, shards,
-                 queue_depth: int, n_active: int, levels: int = 1,
-                 cpu=None) -> None:
-        """Fold one tick's (drained) spans + fleet state into the
-        registry and trace.
+    def end_tick(self, tick: int, phases: PhaseTimer,
+                 subphases: SubPhaseTimer, shards, queue_depth: int,
+                 n_active: int, levels: int = 1) -> None:
+        """Drain one tick's spans from the engine's two timers and fold
+        them + fleet state into the registry and trace.
 
         ``levels`` is how many ladder levels the engine tick advanced (the
         macro-tick factor K when work ran fused, 1 otherwise):
         ``sa_ticks_total`` counts ladder levels, keeping it equal to the
-        engine's ``tick_count`` clock at any K.  ``cpu`` is the tick's
-        per-phase host-thread CPU seconds (the PhaseTimer's second clock).
+        engine's ``tick_count`` clock at any K.  Phase spans feed
+        ``sa_tick_phase_seconds`` (wall), ``sa_shard_phase_seconds_total``
+        and ``sa_tick_phase_cpu_seconds_total`` (the PhaseTimer's second
+        clock); sub-spans feed ``sa_tick_subphase_seconds_total`` only.
         """
+        acc, shard_acc, raw, cpu = phases.drain()
+        compiles, phases.compiles = phases.compiles, {}
+        sub_acc, sub_raw = subphases.drain()
         total = 0.0
         for phase, secs in acc.items():
             self.m_tick_phase.observe(secs, phase)
             total += secs
         for (shard, phase), secs in shard_acc.items():
             self.m_shard_phase.inc(secs, str(shard), phase)
-        for phase, secs in (cpu or {}).items():
+        for phase, secs in cpu.items():
             self.m_phase_cpu.inc(secs, phase)
+        for phase, n in compiles.items():
+            self.m_compiles.inc(n, phase)
+        for name, secs in sub_acc.items():
+            self.m_subphase.inc(secs, name)
         if total:
             self.m_tick.observe(total)
         self.m_ticks.inc(levels)
@@ -575,6 +698,9 @@ class Telemetry:
         if self.trace is not None:
             for phase, shard, t0, t1 in raw:
                 self.trace.span(phase, t0, t1, shard=shard, tick=tick)
+            for name, shard, t0, t1 in sub_raw:
+                self.trace.span(name, t0, t1, shard=shard, tick=tick,
+                                cat="subtick")
 
     def tenant_slot_ticks(self, req_id: int, n_slots: int) -> None:
         self.m_tenant_slot_ticks.inc(n_slots, str(req_id))
@@ -593,14 +719,17 @@ class NullTelemetry:
     def make_phase_timer(self, clock):
         return NULL_PHASE_TIMER
 
+    def make_subphase_timer(self, clock):
+        return NULL_PHASE_TIMER
+
     def decision(self, tick, kind, **fields):
         pass
 
     def plan(self, kind, n_actions):
         pass
 
-    def end_tick(self, tick, acc, shard_acc, raw, shards, queue_depth,
-                 n_active, levels=1, cpu=None):
+    def end_tick(self, tick, phases, subphases, shards, queue_depth,
+                 n_active, levels=1):
         pass
 
     def tenant_slot_ticks(self, req_id, n_slots):

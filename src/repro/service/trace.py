@@ -15,13 +15,13 @@ Layout conventions
 * ``pid`` 0 is the engine process.  ``tid`` 0 carries fleet-wide phase
   spans (schedule/admit); ``tid`` ``shard_index + 1`` carries that
   shard's dispatch/device_wait/materialize/retire spans.  Metadata
-  events name them.
+  events name them.  Sub-spans (category ``"subtick"``, named
+  ``<phase>.<sub>``, telemetry.TICK_SUBPHASES) sit on their phase's
+  track and nest inside it by time.
 * Request lifecycles are **async** events: category ``"request"``, id
   ``req_id`` — ``b`` at submit, ``n`` instants for admit / level /
   preempt / resume / migrate / shrink, ``e`` at the terminal.  Perfetto
   draws each request as one track spanning its queueing + residence.
-* Decision instants (category ``"decision"``) mirror the structured
-  event log (telemetry.py) so the two views cross-reference by tick.
 * Timestamps are **microseconds** on the engine's monotonic epoch — the
   same clock every wall figure in the repo shares (engine.py ``_now``).
 
@@ -75,23 +75,18 @@ class TraceBuilder:
 
     # ---------------------------------------------------------- phase spans
     def span(self, phase: str, t0: float, t1: float,
-             shard: Optional[int] = None, tick: Optional[int] = None) -> None:
-        """One complete ('X') phase span, [t0, t1] in epoch seconds."""
+             shard: Optional[int] = None, tick: Optional[int] = None,
+             cat: str = "tick") -> None:
+        """One complete ('X') span, [t0, t1] in epoch seconds: a tick
+        phase (``cat="tick"``) or a sub-span under one (``"subtick"``)."""
         tid = 0 if shard is None else shard + 1
         if shard is not None:
             self.ensure_shard_track(shard)
-        ev = {"ph": "X", "name": phase, "cat": "tick", "pid": 0, "tid": tid,
+        ev = {"ph": "X", "name": phase, "cat": cat, "pid": 0, "tid": tid,
               "ts": t0 * _US, "dur": max(t1 - t0, 0.0) * _US}
         if tick is not None:
             ev["args"] = {"tick": tick}
         self.events.append(ev)
-
-    # ----------------------------------------------------- decision instants
-    def instant(self, name: str, **args) -> None:
-        """Thread-scoped instant mirroring one structured-log decision."""
-        self.events.append({"ph": "i", "name": name, "cat": "decision",
-                            "pid": 0, "tid": 0, "s": "t",
-                            "ts": self._now_us(), "args": args})
 
     # ------------------------------------------------------ request lifecycle
     def _async(self, ph: str, req_id: int, name: str, args: dict) -> None:
@@ -159,9 +154,9 @@ def validate_trace(doc: dict, schema: Optional[dict] = None) -> List[str]:
     Returns the list of violations (empty == valid).  Phase-span events
     additionally get a semantic check the schema language cannot express:
     every ``X`` event's duration must be non-negative and its phase name
-    drawn from the tick taxonomy.
+    drawn from the tick taxonomy (sub-spans: from its sub-span list).
     """
-    from repro.service.telemetry import TICK_PHASES
+    from repro.service.telemetry import TICK_PHASES, TICK_SUBPHASES
 
     schema = load_schema() if schema is None else schema
     errors: List[str] = []
@@ -175,5 +170,10 @@ def validate_trace(doc: dict, schema: Optional[dict] = None) -> List[str]:
             if ev.get("cat") == "tick" and ev.get("name") not in TICK_PHASES:
                 errors.append(
                     f"$.traceEvents[{i}]: unknown tick phase "
+                    f"{ev.get('name')!r}")
+            if (ev.get("cat") == "subtick"
+                    and ev.get("name") not in TICK_SUBPHASES):
+                errors.append(
+                    f"$.traceEvents[{i}]: unknown tick sub-span "
                     f"{ev.get('name')!r}")
     return errors

@@ -3,7 +3,8 @@
 Tentpole guarantees:
 
 * **zero overhead off**: with telemetry disabled (the default) the
-  engine allocates no span objects and compiles no extra programs;
+  engine allocates no span objects (phases or sub-spans) and compiles
+  no extra programs;
 * **bit-exact on**: enabling metrics + tracing + the event log perturbs
   no trajectory — champion histories match the disabled run and the
   standalone oracle at every ladder level;
@@ -33,8 +34,10 @@ from repro.service import (
     SARequest,
     SAServeEngine,
     SchedulerConfig,
+    SubPhaseTimer,
     Telemetry,
     TICK_PHASES,
+    TICK_SUBPHASES,
     TraceBuilder,
     compile_events,
     run_standalone,
@@ -82,10 +85,12 @@ def _serve(telemetry=None, n=4, n_devices=1, **cfg_kw):
 def test_disabled_allocates_no_spans_and_compiles_nothing_extra():
     compile_before = compile_events()
     spans_before = PhaseTimer.spans_entered
+    subs_before = SubPhaseTimer.spans_entered
     engine, results = _serve()
     assert len(results) == 4
-    # The zero-overhead witness: the class-wide span counter never moved.
+    # The zero-overhead witness: the class-wide span counters never moved.
     assert PhaseTimer.spans_entered == spans_before
+    assert SubPhaseTimer.spans_entered == subs_before
     # And the engine defaults hold: no registry, no trace, no events.
     assert engine.telemetry.enabled is False
     assert engine.telemetry.registry is None
@@ -210,6 +215,10 @@ def test_phase_metrics_cover_the_taxonomy():
     st = engine.stats()
     assert set(st["phases"]["aggregate"]) == set(TICK_PHASES)
     assert st["phases"]["per_shard"]["0"]["dispatch"] > 0
+    assert st["phases"]["per_shard"]["0"] == {
+        phase: secs for (shard, phase), secs
+        in tel.registry["sa_shard_phase_seconds_total"].series.items()
+        if shard == "0"}
 
 
 def test_phase_timer_tracks_host_cpu_alongside_wall():
@@ -261,6 +270,9 @@ def test_metrics_survive_drain_and_resize():
     phase_keys = {k for k in tel.registry["sa_shard_phase_seconds_total"]
                   .series if k[0] == str(victim)}
     assert phase_keys
+    # ...and stats() reads them from there, so they outlive the shard.
+    assert set(engine.stats()["phases"]["per_shard"][str(victim)]) == \
+        {phase for _, phase in phase_keys}
     # ...and its lifecycle shows up in decisions + events.
     decisions = tel.registry["sa_scheduler_decisions_total"]
     assert decisions.value("drain") == 1
@@ -326,11 +338,13 @@ def test_event_log_is_deterministic_and_replayable():
 # ----------------------------------------------------- macro-tick fusion
 def test_macro_tick_disabled_telemetry_allocates_zero_spans():
     """The zero-overhead guarantee survives fusion: a K=4 run with
-    telemetry off never enters a span."""
+    telemetry off never enters a span or a sub-span."""
     spans_before = PhaseTimer.spans_entered
+    subs_before = SubPhaseTimer.spans_entered
     engine, results = _serve(macro_k=4)
     assert len(results) == 4
     assert PhaseTimer.spans_entered == spans_before
+    assert SubPhaseTimer.spans_entered == subs_before
     assert engine.telemetry.enabled is False
 
 
@@ -404,3 +418,141 @@ def test_serve_sa_cli_trace_events_metrics(tmp_path, capsys):
     assert validate_trace(trace) == []
     assert len(EventLog.loads(events_p.read_text())) > 0
     assert "# TYPE sa_ticks_total counter" in metrics_p.read_text()
+
+
+# ------------------------------------------------- sub-spans and counters
+def _one_group(tel, macro_k=1, n_levels_cut=None):
+    """One request of three slots: a 3-block group padded to 4."""
+    engine = SAServeEngine(_cfg(macro_k=macro_k), telemetry=tel)
+    engine.submit(_req(0, n_chains=3 * CPS))
+    if n_levels_cut is not None:
+        engine.tick()
+        engine.truncate_active(0, n_levels_cut)
+    res = engine.run(max_ticks=400)[0]
+    return engine, res
+
+
+def test_subspans_enter_and_stay_out_of_the_phase_sums():
+    tel = Telemetry(trace=TraceBuilder())
+    subs_before = SubPhaseTimer.spans_entered
+    engine, _ = _one_group(tel)
+    assert SubPhaseTimer.spans_entered > subs_before     # the witness is live
+    sub = tel.registry["sa_tick_subphase_seconds_total"]
+    got = {name for (name,) in sub.series}
+    assert got == {"admit.init_state", "dispatch.pack", "dispatch.h2d",
+                   "dispatch.launch", "materialize.d2h",
+                   "materialize.scatter", "materialize.fold"}
+    assert got <= set(TICK_SUBPHASES)
+    # The phase histogram holds the six phases and nothing else.
+    phases = {p for (p,) in tel.registry["sa_tick_phase_seconds"].series}
+    assert phases == set(TICK_PHASES)
+    assert set(engine.stats()["phases"]["aggregate"]) == set(TICK_PHASES)
+    # A sub-span lies inside its phase: the sums cannot exceed the phase's.
+    agg = engine.stats()["phases"]["aggregate"]
+    for name, in sub.series:
+        phase = name.split(".")[0]
+        assert sub.value(name) <= agg[phase]["sum"] + 1e-6
+    # In the trace document each sub-span nests in a span of its phase on
+    # the same track.
+    doc = tel.trace.to_json()
+    assert validate_trace(doc) == []
+    spans = [e for e in doc["traceEvents"] if e.get("cat") == "tick"]
+    subs = [e for e in doc["traceEvents"] if e.get("cat") == "subtick"]
+    assert {e["name"] for e in subs} == got
+    for e in subs:
+        parent = e["name"].split(".")[0]
+        assert any(p["name"] == parent and p["tid"] == e["tid"]
+                   and p["ts"] <= e["ts"] + 1e-3
+                   and e["ts"] + e["dur"] <= p["ts"] + p["dur"] + 1e-3
+                   for p in spans), e
+
+
+def test_trace_schema_rejects_an_unknown_subspan():
+    bad = {"traceEvents": [
+        {"ph": "X", "name": "dispatch.warp", "cat": "subtick", "pid": 0,
+         "tid": 1, "ts": 0, "dur": 1}], "displayTimeUnit": "ms"}
+    assert any("unknown tick sub-span" in e for e in validate_trace(bad))
+
+
+def test_state_bytes_and_block_steps_match_hand_counts_at_k1():
+    tel = Telemetry()
+    engine, res = _one_group(tel)
+    levels, n_steps, dim = res.levels_run, 10, 4
+    assert levels == engine.group_launches > 1
+    per_launch = 4 * CPS * dim * 4            # n_padded x cps x dim x f32
+    state = tel.registry["sa_state_bytes_total"]
+    assert state.value("h2d") == state.value("d2h") == levels * per_launch
+    steps = tel.registry["sa_block_steps_total"]
+    assert steps.value("live") == levels * 3 * n_steps
+    assert steps.value("padded") == levels * 1 * n_steps
+    assert steps.value("dead") == 0
+    # The K=1 path never touches the fused path's buffer cache.
+    assert tel.registry["sa_state_buffer_total"].series == {}
+
+
+def test_block_steps_and_buffer_cache_match_hand_counts_at_k4():
+    """K=4 over a ladder cut to 6 levels: the first macro-tick runs 4 live
+    levels, the second 2 live and 2 dead; the state is packed once
+    (repack) and reused (hit) after."""
+    tel = Telemetry()
+    engine, res = _one_group(tel, macro_k=4, n_levels_cut=6)
+    n_steps, dim = 10, 4
+    assert res.levels_run == 6 and engine.group_launches == 2
+    steps = tel.registry["sa_block_steps_total"]
+    assert steps.value("live") == 3 * 6 * n_steps
+    assert steps.value("dead") == 3 * 2 * n_steps
+    assert steps.value("padded") == 1 * 2 * 4 * n_steps
+    buf = tel.registry["sa_state_buffer_total"]
+    assert (buf.value("repack"), buf.value("hit")) == (1, 1)
+    state = tel.registry["sa_state_bytes_total"]
+    assert state.value("h2d") == 4 * CPS * dim * 4
+    assert state.value("d2h") == 0            # the state stayed on device
+
+
+def test_compiles_are_counted_in_the_phase_that_ran_them():
+    _group_tick.clear_cache()
+    tel = Telemetry()
+    _one_group(tel)
+    compiles = tel.registry["sa_compiles_total"]
+    assert compiles.value("dispatch") >= 1     # the group program's compile
+    assert sum(compiles.series.values()) <= \
+        tel.registry["sa_jax_compile_events_total"].value()
+
+
+def test_device_scopes_name_the_group_program_stages():
+    """The scopes are in the lowered program's op metadata: the sweep, the
+    controls, the exchange and each exchange stage."""
+    cps, n = CPS, 4 * CPS
+    f32, u32, i32 = np.float32, np.uint32, np.int32
+    args = (np.zeros((n, 4), f32), np.zeros(4, i32), np.ones(4, f32),
+            np.zeros(4, u32), np.zeros(4, u32), np.zeros(4, u32),
+            np.zeros(4, u32), np.zeros(4, f32), np.zeros(n, i32),
+            np.ones(n, bool), np.zeros(n, np.int8), np.ones(n, f32),
+            np.arange(n, dtype=i32), np.zeros(n, u32),
+            np.arange(n, dtype=i32), np.arange(1, n + 1, dtype=i32))
+    text = _group_tick.lower(
+        *args, n_steps=10, blk=cps, variant="delta", use_pallas=False,
+        interpret=False, num_segments=5).as_text(debug_info=True)
+    for scope in ("sa.sweep", "sa.controls", "sa.exchange/champion",
+                  "sa.exchange/adopt", "sa.exchange/pt_swap",
+                  "sa.exchange/pa_resample"):
+        assert scope in text, scope
+
+
+def test_profiler_trace_holds_the_phase_and_subspan_annotations(tmp_path):
+    from jax.profiler import ProfileData
+
+    engine = SAServeEngine(_cfg(), telemetry=Telemetry())
+    engine.submit(_req(0, n_chains=3 * CPS))
+    engine.tick()                              # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        engine.tick()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.glob("**/*.xplane.pb"))
+    host = next(p for p in ProfileData.from_file(str(path)).planes
+                if p.name == "/host:CPU")
+    names = {e.name for line in host.lines for e in line.events}
+    assert {"sa.dispatch", "sa.dispatch.h2d", "sa.materialize.d2h",
+            "sa.device_wait", "sa.dispatch.launch"} <= names
