@@ -106,8 +106,8 @@ from repro.service.sharding import EngineShard, make_shard, make_shards
 from repro.service.slots import ActiveJob, SwappedJob
 from repro.service.telemetry import NULL as NULL_TELEMETRY
 
-# The fused macro-tick program donates its input state buffer (the double
-# buffer ping-pongs between launches).  Backends without donation support
+# Every group program donates its input state buffer (the double buffer
+# ping-pongs between launches).  Backends without donation support
 # (CPU) warn instead of reusing the buffer — functionally identical, so
 # silence exactly that warning.
 warnings.filterwarnings(
@@ -175,7 +175,8 @@ def _chain_controls(T_blk, seed_blk, base_blk, lvl0, mcode, t_rung, blk: int):
 
 
 @partial(jax.jit, static_argnames=("n_steps", "blk", "variant",
-                                   "use_pallas", "interpret", "num_segments"))
+                                   "use_pallas", "interpret", "num_segments"),
+         donate_argnums=(0,))
 def _group_tick(x, kid_blk, T_blk, seed_blk, step0_blk, base_blk, lvl0_blk,
                 dbeta_blk, seg, adopt, mcode, t_rung, partner, pairlo,
                 seg_lo, seg_hi, *, n_steps: int, blk: int, variant: str,
@@ -195,7 +196,9 @@ def _group_tick(x, kid_blk, T_blk, seed_blk, step0_blk, base_blk, lvl0_blk,
     The three stages run under ``jax.named_scope``s — ``sa.controls``,
     ``sa.sweep``, ``sa.exchange`` — that name their device ops in a
     profiler trace (metadata only: the program is the same).  Every
-    group program uses the same three.
+    group program uses the same three.  ``x`` is **donated**, as in the
+    fused program: the state stays on the device between levels while the
+    group's membership is stable.
     """
     with jax.named_scope("sa.controls"):
         sched, T_chain, seed_c, cidx, lvl_abs = _chain_controls(
@@ -292,7 +295,8 @@ def _group_tick_fused(x, kid_blk, T_lvls, seed_blk, step0_blk, base_blk,
 
 
 @partial(jax.jit, static_argnames=("n_steps", "blk", "use_pallas",
-                                   "interpret", "num_segments"))
+                                   "interpret", "num_segments"),
+         donate_argnums=(0,))
 def _group_tick_qap(x, F_blk, D_blk, T_blk, seed_blk, step0_blk, base_blk,
                     lvl0_blk, seg, adopt, mcode, t_rung, partner, pairlo,
                     seg_lo, seg_hi, *, n_steps: int, blk: int,
@@ -309,7 +313,8 @@ def _group_tick_qap(x, F_blk, D_blk, T_blk, seed_blk, step0_blk, base_blk,
     ``dbeta``: the QAP sweep is always delta-evaluated (bitwise equal to a
     full evaluation) and permutation requests are method-'sa' only, so the
     PA reweighting increment is identically zero.  A separate jit (typed
-    on int32 x) naturally pins one compiled program per family.
+    on int32 x) naturally pins one compiled program per family.  ``x``
+    is donated, as in :func:`_group_tick`.
     """
     with jax.named_scope("sa.controls"):
         sched, T_chain, seed_c, cidx, lvl_abs = _chain_controls(
@@ -1116,10 +1121,11 @@ class SAServeEngine:
         Two passes over the shards: *launch* every ``(shard, dim, N)``
         group's device program first (JAX dispatch is asynchronous, so
         programs on different devices execute concurrently), then
-        *collect* — materialize results on host, scatter blocks back and
-        retire finished requests.  Collecting inline per group would
-        serialize the shards: ``np.asarray`` blocks on the transfer, and
-        device k+1 would not launch until device k had fully finished.
+        *collect* — read the champions back to host, fold them and retire
+        finished requests; the chain state stays on the device.
+        Collecting inline per group would serialize the shards:
+        ``np.asarray`` blocks on the transfer, and device k+1 would not
+        launch until device k had fully finished.
 
         Macro-ticks (K>1): the top of a tick is a **macro-tick boundary**
         — scripted ops, admission, preemption, migration and rebalancing
@@ -1181,6 +1187,9 @@ class SAServeEngine:
                         self._launch_group_fused(shard, family, dim,
                                                  n_steps, jobs))
                     self.group_launches += 1
+                # A group that ran nothing this tick keeps no device buffer.
+                for key in shard.group_cache.keys() - groups.keys():
+                    del shard.group_cache[key]
         if self.telemetry.enabled:
             self.telemetry.m_launches.inc(len(launches))
             # Fence: wait for each shard's device arrays so device compute
@@ -1189,7 +1198,7 @@ class SAServeEngine:
             # in flight, so waiting shard-by-shard keeps the overlap.
             for launch in launches:
                 with pt("device_wait", launch[0].index):
-                    jax.block_until_ready(launch[4])
+                    jax.block_until_ready(launch[3])
         finished = []
         advance = 1
         for launch in launches:
@@ -1232,27 +1241,20 @@ class SAServeEngine:
                                     self.n_active, levels=levels)
 
     def _collect_group(self, shard: EngineShard, n_steps: int,
-                       jobs: List[ActiveJob], slot_list, outs):
-        """Materialize one group's results and advance its jobs one level;
+                       jobs: List[ActiveJob], outs):
+        """Fold one group's champions and advance its jobs one level;
         returns the finished ``(shard, job, reason, finish_tick)`` tuples
         for the caller's retire pass (slot frees can wait: admission
         happens at the top of the next tick, so deferring the release is
         equivalent)."""
-        cps = self.cfg.chains_per_slot
         tel = self.telemetry
         sub = self._sub
         with sub("materialize.d2h", shard.index):
-            x2, xb, fb = (np.asarray(outs[0]), np.asarray(outs[2]),
-                          np.asarray(outs[3]))
+            # Only what the host folds: the chain state stays on the
+            # device (the pool holds refs into outs[0], set at launch).
+            xb, fb = np.asarray(outs[2]), np.asarray(outs[3])
             fxh = (np.asarray(outs[1])
                    if any(j.req.pa_ess_ratio > 0 for j in jobs) else None)
-        if tel.enabled:
-            tel.m_state_bytes.inc(x2.nbytes, "d2h")
-        with sub("materialize.scatter", shard.index):
-            for b, (s, job) in enumerate(slot_list):
-                # Copy: a bare slice would alias (and pin) the whole
-                # padded buffer.
-                shard.pool.set_block(s, x2[b * cps:(b + 1) * cps].copy())
         finished = []
         row0 = 0
         with sub("materialize.fold", shard.index):
@@ -1283,7 +1285,7 @@ class SAServeEngine:
         return finished
 
     def _collect_group_fused(self, shard: EngineShard, n_steps: int,
-                             jobs: List[ActiveJob], slot_list, outs,
+                             jobs: List[ActiveJob], outs,
                              planned: Dict[int, int]):
         """Fold one fused macro-tick's results on host.
 
@@ -1410,14 +1412,9 @@ class SAServeEngine:
         the K=1 cursor update) and threaded as a ``(K, n_blocks)`` SMEM
         array.
 
-        The double buffer: if every slot of the group still references
-        this group's cached output buffer at its packed rows — membership,
-        order and content unchanged since the last boundary — the host
-        repack and transfer of chain state are skipped entirely and the
-        cached buffer is donated straight back to the device.  Any
-        checkpoint/migrate/shrink/retire in between breaks the signature
-        and falls back to a host repack (get_block materializes refs on
-        demand).
+        The chain state comes from :meth:`_group_state` (the double
+        buffer, shared with the K=1 path) and stays on the device after
+        the launch (:meth:`_keep_group_state`).
         """
         cps = self.cfg.chains_per_slot
         K = self.cfg.macro_k
@@ -1498,37 +1495,9 @@ class SAServeEngine:
             mcode, t_rung, partner2, pairlo2, seg_lo, seg_hi = \
                 self._pack_class_controls(jobs, n_padded, 2)
 
-            cache = shard.group_cache.get((family, dim, n_steps))
-            x_dev = None
-            if cache is not None and cache["n_padded"] == n_padded:
-                buf = cache["buf"]
-                for b, (s, _job) in enumerate(slot_list):
-                    ref = shard.pool.device_ref(s)
-                    if (ref is None or ref.buf is not buf
-                            or ref.start != b * cps):
-                        break
-                else:
-                    x_dev = buf          # cache hit: skip repack + transfer
-            x = None
-            if x_dev is None:
-                x = np.empty((n_padded * cps, dim),
-                             np.int32 if is_qap else np.float32)
-                if tel.enabled:
-                    # Device-resident blocks come back to host for the
-                    # repack (get_block materializes them).
-                    n_refs = sum(shard.pool.device_ref(s) is not None
-                                 for s, _job in slot_list)
-                    tel.m_state_bytes.inc(n_refs * cps * dim * x.itemsize,
-                                          "d2h")
-                for b, (s, _job) in enumerate(slot_list):
-                    x[b * cps:(b + 1) * cps] = shard.pool.get_block(s)
-                for b in range(n_blocks, n_padded):
-                    x[b * cps:(b + 1) * cps] = x[:cps]
-
-        dev = shard.device
+        x_dev = self._group_state(shard, (family, dim, n_steps), slot_list,
+                                  n_padded)
         with sub("dispatch.h2d", shard.index):
-            if x is not None:
-                x_dev = jax.device_put(x, dev)
             # One batched transfer for all control arrays: separate
             # device_put dispatches were the dominant per-launch host cost
             # once the state buffer started cache-hitting.
@@ -1536,12 +1505,12 @@ class SAServeEngine:
                 ctrl = jax.device_put(
                     (F_blk, D_blk, T_lvls, seed_blk, step0_blk, base_blk,
                      levels_blk, lvl0_blk, seg, adopt, mcode, t_rung,
-                     partner2, pairlo2, seg_lo, seg_hi), dev)
+                     partner2, pairlo2, seg_lo, seg_hi), shard.device)
             else:
                 ctrl = jax.device_put(
                     (kid_blk, T_lvls, seed_blk, step0_blk, base_blk,
                      levels_blk, lvl0_blk, dbeta_lvls, seg, adopt, mcode,
-                     t_rung, partner2, pairlo2, seg_lo, seg_hi), dev)
+                     t_rung, partner2, pairlo2, seg_lo, seg_hi), shard.device)
         with sub("dispatch.launch", shard.index):
             if is_qap:
                 outs = _group_tick_qap_fused(
@@ -1558,30 +1527,20 @@ class SAServeEngine:
                     interpret=self.cfg.interpret,
                     num_segments=self.cfg.n_slots + 1)
         if tel.enabled:
-            tel.m_state_buffer.inc(1, "repack" if x is not None else "hit")
-            if x is not None:
-                tel.m_state_bytes.inc(x.nbytes, "h2d")
             live = sum(planned[job.rid] * len(job.slots) for job in jobs)
             tel.m_block_steps.inc(live * n_steps, "live")
             tel.m_block_steps.inc((n_blocks * K - live) * n_steps, "dead")
             tel.m_block_steps.inc((n_padded - n_blocks) * K * n_steps,
                                   "padded")
-        out_x = outs[0]
-        # The group's state now lives in the output buffer.  Point every
-        # slot there (lazily — materialized only by checkpoint/migrate/
-        # shrink or a cache-miss repack) and arm the double buffer for the
-        # next boundary.  The donated input has no readers left: every
-        # ref into it was just replaced.
-        for b, (s, _job) in enumerate(slot_list):
-            shard.pool.set_device_block(s, out_x, b * cps, (b + 1) * cps)
-        shard.group_cache[(family, dim, n_steps)] = {"buf": out_x,
-                                                     "n_padded": n_padded}
-        return shard, n_steps, jobs, slot_list, outs, planned
+        self._keep_group_state(shard, (family, dim, n_steps), slot_list,
+                               n_padded, outs[0])
+        return shard, n_steps, jobs, outs, planned
 
     def _launch_group(self, shard: EngineShard, family: str, dim: int,
                       n_steps: int, jobs: List[ActiveJob]):
-        """Pack the group's slots and launch its device program (async);
-        returns the collect-pass arguments.  ``family`` picks the program
+        """Pack the group's controls, reuse (or rebuild) its device state
+        buffer, and launch its one-level device program (async); returns
+        the collect-pass arguments.  ``family`` picks the program
         (Metropolis vs QAP pairwise-exchange) and the state dtype; see
         :meth:`_launch_group_fused`."""
         cps = self.cfg.chains_per_slot
@@ -1599,8 +1558,6 @@ class SAServeEngine:
         tel = self.telemetry
         sub = self._sub
         with sub("dispatch.pack", shard.index):
-            x = np.empty((n_padded * cps, dim),
-                         np.int32 if is_qap else np.float32)
             kid_blk = np.empty((n_padded,), np.int32)
             if is_qap:
                 F_blk = np.empty((n_padded * dim, dim), np.float32)
@@ -1614,7 +1571,6 @@ class SAServeEngine:
             seg = np.empty((n_padded * cps,), np.int32)
             adopt = np.empty((n_padded * cps,), bool)
             for b, (s, job) in enumerate(slot_list):
-                x[b * cps:(b + 1) * cps] = shard.pool.get_block(s)
                 kid_blk[b] = np.int32(job.req.kid)
                 if is_qap:
                     inst = job.req.instance
@@ -1630,10 +1586,11 @@ class SAServeEngine:
                 seg[b * cps:(b + 1) * cps] = job.rid
                 adopt[b * cps:(b + 1) * cps] = (
                     job.req.method == "sa" and job.req.exchange == "sync")
-            # Dummy pad blocks: replicate block 0, claim the reserved
-            # segment n_slots, never adopt. They cost lanes, not correctness.
+            # Pad blocks run block 0's controls, claim the reserved
+            # segment n_slots and never adopt: whatever state their rows
+            # hold (a copy of block 0 when packed, a reused buffer's old
+            # rows otherwise) costs lanes, not correctness.
             for b in range(n_blocks, n_padded):
-                x[b * cps:(b + 1) * cps] = x[:cps]
                 kid_blk[b] = kid_blk[0]
                 if is_qap:
                     F_blk[b * dim:(b + 1) * dim] = F_blk[:dim]
@@ -1649,36 +1606,98 @@ class SAServeEngine:
 
         # Committed transfers pin the group's program to the shard's mesh
         # device.  The call returns device arrays without blocking; the
-        # collect pass materializes them after every shard has launched.
-        dev = shard.device
+        # collect pass reads the champions after every shard has launched.
+        x_dev = self._group_state(shard, (family, dim, n_steps), slot_list,
+                                  n_padded)
         with sub("dispatch.h2d", shard.index):
             if is_qap:
-                args = (x, F_blk, D_blk, T_blk, seed_blk, step0_blk,
-                        base_blk, lvl0_blk, seg, adopt, mcode, t_rung,
-                        partner[0], pairlo[0], seg_lo, seg_hi)
+                ctrl = jax.device_put(
+                    (F_blk, D_blk, T_blk, seed_blk, step0_blk, base_blk,
+                     lvl0_blk, seg, adopt, mcode, t_rung, partner[0],
+                     pairlo[0], seg_lo, seg_hi), shard.device)
             else:
-                args = (x, kid_blk, T_blk, seed_blk, step0_blk, base_blk,
-                        lvl0_blk, dbeta_blk, seg, adopt, mcode, t_rung,
-                        partner[0], pairlo[0], seg_lo, seg_hi)
-            args = [jax.device_put(a, dev) for a in args]
+                ctrl = jax.device_put(
+                    (kid_blk, T_blk, seed_blk, step0_blk, base_blk, lvl0_blk,
+                     dbeta_blk, seg, adopt, mcode, t_rung, partner[0],
+                     pairlo[0], seg_lo, seg_hi), shard.device)
         with sub("dispatch.launch", shard.index):
             if is_qap:
                 outs = _group_tick_qap(
-                    *args, n_steps=n_steps, blk=cps,
+                    x_dev, *ctrl, n_steps=n_steps, blk=cps,
                     use_pallas=self._use_pallas,
                     interpret=self.cfg.interpret,
                     num_segments=self.cfg.n_slots + 1)
             else:
                 outs = _group_tick(
-                    *args, n_steps=n_steps, blk=cps,
+                    x_dev, *ctrl, n_steps=n_steps, blk=cps,
                     variant=self.cfg.variant, use_pallas=self._use_pallas,
                     interpret=self.cfg.interpret,
                     num_segments=self.cfg.n_slots + 1)
         if tel.enabled:
-            tel.m_state_bytes.inc(x.nbytes, "h2d")
             tel.m_block_steps.inc(n_blocks * n_steps, "live")
             tel.m_block_steps.inc((n_padded - n_blocks) * n_steps, "padded")
-        return shard, n_steps, jobs, slot_list, outs
+        self._keep_group_state(shard, (family, dim, n_steps), slot_list,
+                               n_padded, outs[0])
+        return shard, n_steps, jobs, outs
+
+    def _group_state(self, shard: EngineShard, key: Tuple[str, int, int],
+                     slot_list: List[Tuple[int, ActiveJob]], n_padded: int):
+        """The group's packed chain state on the shard's device, for a
+        launch that donates it (both launch paths).
+
+        The double buffer: if every slot of the group still references
+        this group's cached output buffer at its packed rows, with the same
+        padding — membership, order and content unchanged since the last
+        launch — the host repack and transfer are skipped and the cached
+        buffer goes straight back in.  Any admit/checkpoint/migrate/shrink/
+        retire in between breaks the signature and falls back to a host
+        repack from the pool (``get_block`` reads each device buffer back
+        once) and an upload.
+        """
+        family, dim, _ = key
+        cps = self.cfg.chains_per_slot
+        pool = shard.pool
+        tel = self.telemetry
+        with self._sub("dispatch.pack", shard.index):
+            cache = shard.group_cache.get(key)
+            if cache is not None and cache["n_padded"] == n_padded:
+                buf = cache["buf"]
+                for b, (s, _job) in enumerate(slot_list):
+                    ref = pool.device_ref(s)
+                    if (ref is None or ref.buf is not buf
+                            or ref.start != b * cps):
+                        break
+                else:
+                    if tel.enabled:
+                        tel.m_state_buffer.inc(1, "hit")
+                    return buf
+            read0 = pool.bytes_read
+            x = np.empty((n_padded * cps, dim),
+                         np.int32 if family == fam_mod.FAMILY_PERMUTATION
+                         else np.float32)
+            for b, (s, _job) in enumerate(slot_list):
+                x[b * cps:(b + 1) * cps] = pool.get_block(s)
+            for b in range(len(slot_list), n_padded):
+                x[b * cps:(b + 1) * cps] = x[:cps]
+        with self._sub("dispatch.h2d", shard.index):
+            x_dev = jax.device_put(x, shard.device)
+        if tel.enabled:
+            tel.m_state_buffer.inc(1, "repack")
+            tel.m_state_bytes.inc(pool.bytes_read - read0, "d2h")
+            tel.m_state_bytes.inc(x.nbytes, "h2d")
+        return x_dev
+
+    @staticmethod
+    def _keep_group_state(shard: EngineShard, key: Tuple[str, int, int],
+                          slot_list: List[Tuple[int, ActiveJob]],
+                          n_padded: int, out_x) -> None:
+        """The group's state now lives in the launch's output buffer.
+        Point every slot there (lazily — materialized only by checkpoint/
+        migrate/shrink or a cache-miss repack) and arm the double buffer
+        for the next launch.  The donated input has no readers left: every
+        ref into it was just replaced."""
+        shard.pool.set_device_blocks([s for s, _job in slot_list], out_x)
+        shard.group_cache[key] = {"buf": out_x, "n_padded": n_padded}
 
     def _finish_reason(self, job: ActiveJob) -> Optional[str]:
         req = job.req

@@ -89,14 +89,14 @@ class EngineShard:
     draining: bool = False      # no new placements; evacuating to retire
     group_cache: dict = dataclasses.field(default_factory=dict)
                                 # (family, dim, N) -> {"buf": device array,
-                                # "n_padded": int}: the fused macro-tick
-                                # path's double buffer.  When a group's
+                                # "n_padded": int}: the launch paths'
+                                # double buffer.  When a group's
                                 # membership is unchanged since its last
                                 # launch (every slot still references this
                                 # buffer at its packed rows), the host
                                 # repack + transfer is skipped and the
                                 # buffer is donated straight back to the
-                                # next launch (engine._launch_group_fused)
+                                # next launch (engine._group_state)
 
     @property
     def jobs(self):

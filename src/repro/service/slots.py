@@ -10,9 +10,9 @@ so RNG streams are invariant to which physical slots the scheduler picked
 KV rows).
 
 Slot state is *logically* host-side numpy; device arrays are packed per
-dispatch group by the engine each tick.  Under macro-tick fusion the
-engine leaves chain state device-resident between launches: a slot may
-hold a :class:`DeviceBlockRef` — a lazy view into the group's packed
+dispatch group by the engine.  The engine leaves chain state
+device-resident between launches: after a launch every slot of the group
+holds a :class:`DeviceBlockRef` — a lazy view into the group's packed
 device output — instead of a numpy block.  ``get_block`` materializes the
 ref to host on demand (checkpoint, migration, shrink, repack), so every
 consumer of the pool keeps its host-numpy contract while the steady-state
@@ -40,23 +40,30 @@ from repro.service.request import SARequest
 class DeviceBlockRef:
     """Lazy slot content: rows ``[start, stop)`` of a packed device array.
 
-    Created by the engine's fused launch path (the group's donated output
+    Created by the engine's launch path (the group's donated output
     buffer), materialized to host numpy on first ``get_block``.  Identity
     of ``buf`` is what the engine's dispatch cache keys on: if every slot
     of a group still references the same buffer at the same rows, the
     packed state on device is current and the host repack + transfer can
     be skipped (and the buffer donated back to the next launch).
+
+    ``host`` is a one-element list shared by every ref into ``buf``: the
+    first ref to materialize reads the whole buffer back in one transfer,
+    and the others slice that host copy.
     """
 
-    __slots__ = ("buf", "start", "stop")
+    __slots__ = ("buf", "start", "stop", "host")
 
-    def __init__(self, buf, start: int, stop: int):
+    def __init__(self, buf, start: int, stop: int, host: list):
         self.buf = buf
         self.start = start
         self.stop = stop
+        self.host = host
 
     def materialize(self) -> np.ndarray:
-        return np.asarray(self.buf[self.start:self.stop])
+        if self.host[0] is None:
+            self.host[0] = np.asarray(self.buf)
+        return self.host[0][self.start:self.stop]
 
 
 @dataclasses.dataclass
@@ -164,6 +171,7 @@ class SlotPool:
         self.owner = np.full((n_slots,), -1, np.int32)       # rid or -1
         self.chain_base = np.zeros((n_slots,), np.uint32)    # request chain offset
         self._x: List[Optional[np.ndarray]] = [None] * n_slots
+        self.bytes_read = 0     # device buffers read back by get_block
 
     # ------------------------------------------------------------- queries
     @property
@@ -184,20 +192,24 @@ class SlotPool:
         x = self._x[slot]
         assert x is not None, f"slot {slot} is empty"
         if isinstance(x, DeviceBlockRef):
-            # Materialize the device-resident block to host and cache it:
+            # Materialize the device-resident block to host and keep it:
             # checkpoint/migrate/shrink and cache-miss repacks all come
-            # through here, and repeated reads must not re-transfer.
+            # through here, and repeated reads must not re-transfer.  The
+            # block is a view of its buffer's host copy, which lives until
+            # the group's next launch re-points or its job frees the slots.
+            if x.host[0] is None:
+                self.bytes_read += x.buf.nbytes
             x = x.materialize()
             self._x[slot] = x
         return x
 
-    def set_block(self, slot: int, x: np.ndarray) -> None:
-        self._x[slot] = x
-
-    def set_device_block(self, slot: int, buf, start: int, stop: int) -> None:
-        """Point ``slot`` at rows [start, stop) of a packed device array
-        (the fused launch's output) instead of a host copy."""
-        self._x[slot] = DeviceBlockRef(buf, start, stop)
+    def set_device_blocks(self, slots: List[int], buf) -> None:
+        """Point ``slots[b]`` at rows ``[b*cps, (b+1)*cps)`` of the packed
+        device array ``buf`` (a launch's output) instead of a host copy."""
+        cps = self.chains_per_slot
+        host = [None]
+        for b, s in enumerate(slots):
+            self._x[s] = DeviceBlockRef(buf, b * cps, (b + 1) * cps, host)
 
     def device_ref(self, slot: int) -> Optional[DeviceBlockRef]:
         """The slot's un-materialized device ref, or None if host-resident."""

@@ -55,7 +55,7 @@ from jax.profiler import TraceAnnotation
 #:   admit        — executing the plans (checkpoint/restore, slot assignment)
 #:   dispatch     — host-side packing + async device-program launches
 #:   device_wait  — block_until_ready fence: device compute the host waits on
-#:   materialize  — device->host transfers + scattering blocks back to slots
+#:   materialize  — device->host reads of the champions, folded on host
 #:   retire       — finish checks, result records, slot release
 TICK_PHASES = ("schedule", "admit", "dispatch", "device_wait",
                "materialize", "retire")
@@ -69,11 +69,10 @@ TICK_PHASES = ("schedule", "admit", "dispatch", "device_wait",
 #:   dispatch.launch      — the jitted group program's call (the enqueue;
 #:                          a compile lands here)
 #:   materialize.d2h      — np.asarray reads of the program's outputs
-#:   materialize.scatter  — copying blocks back into their slots
 #:   materialize.fold     — champion fold, history and finish checks
 TICK_SUBPHASES = ("admit.init_state", "admit.restore", "dispatch.pack",
                   "dispatch.h2d", "dispatch.launch", "materialize.d2h",
-                  "materialize.scatter", "materialize.fold")
+                  "materialize.fold")
 
 #: Profiler annotation names, built once: ``sa.<phase>`` and
 #: ``sa.<phase>.<sub>``.
@@ -622,8 +621,8 @@ class Telemetry:
             ("phase",))
         self.m_state_bytes = r.counter(
             "sa_state_bytes_total",
-            "Chain-state bytes moved between host and device by the "
-            "tick's dispatch and materialize", ("direction",))
+            "Chain-state bytes moved between host and device by launches "
+            "that pack the state anew on host", ("direction",))
         self.m_block_steps = r.counter(
             "sa_block_steps_total",
             "Block-steps launched (one slot block, one Metropolis step): "
@@ -631,7 +630,7 @@ class Telemetry:
             ("kind",))
         self.m_state_buffer = r.counter(
             "sa_state_buffer_total",
-            "Fused launches whose device state buffer was reused (hit) or "
+            "Launches whose device state buffer was reused (hit) or "
             "packed anew on host (repack)", ("result",))
 
     # -- hooks the engine calls (every one a no-op on NullTelemetry) --

@@ -118,16 +118,29 @@ def test_k_exceeding_remaining_levels_truncates_cleanly():
 
 
 def test_k1_degenerate_path_compiles_no_fused_programs():
-    """macro_k=1 must keep the classic per-level launch path exactly: no
-    fused program is traced, no device-resident block refs are created,
-    and the dispatch cache stays empty."""
+    """macro_k=1 keeps the one-level program (no fused program is traced)
+    but shares the fused path's state residency: after a launch every
+    active slot holds a device ref into its group's cached output
+    buffer, and the dispatch cache is armed."""
     if not (hasattr(_group_tick_fused, "clear_cache")
             and hasattr(_group_tick_fused, "_cache_size")):
         pytest.skip("kernel cache introspection unavailable")
     _group_tick_fused.clear_cache()
-    _, engine, _ = _serve(_mix(), k=1)
+    cfg = _cfg(k=1, n_devices=2)
+    engine = SAServeEngine(cfg)
+    for r in _mix():
+        engine.submit(r)
+    for _ in range(2):
+        engine.tick()
+        for shard in engine.shards:
+            bufs = {id(e["buf"]) for e in shard.group_cache.values()}
+            assert len(bufs) == len(shard.group_cache) >= 1
+            for job in shard.rids.jobs.values():
+                for s in job.slots:
+                    ref = shard.pool.device_ref(s)
+                    assert ref is not None and id(ref.buf) in bufs
+    engine.run(max_ticks=2000)
     assert _group_tick_fused._cache_size() == 0
-    assert all(not s.group_cache for s in engine.shards)
     _, engine, _ = _serve(_mix(), k=4)
     assert _group_tick_fused._cache_size() >= 1
     assert any(s.group_cache for s in engine.shards)
@@ -196,13 +209,15 @@ def test_open_loop_stream_bit_exact_at_k4():
 
 
 # ------------------------------------------------- double-buffer dispatch
-def test_double_buffer_flips_and_cache_hits_on_stable_membership():
+@pytest.mark.parametrize("k", (1, 4))
+def test_double_buffer_flips_and_cache_hits_on_stable_membership(k):
     """Steady state: each launch donates the previous output buffer back
     in (ping-pong), so the cached buffer identity changes every macro-
     tick and every slot ref points into the *current* cache buffer."""
     reqs = [_req(0), _req(1, objective="ackley")]
-    cfg = _cfg(k=4, n_slots=2)
-    engine = SAServeEngine(cfg)
+    cfg = _cfg(k=k, n_slots=2)
+    tel = Telemetry()
+    engine = SAServeEngine(cfg, telemetry=tel)
     for r in reqs:
         engine.submit(r)
     bufs = []
@@ -216,19 +231,22 @@ def test_double_buffer_flips_and_cache_hits_on_stable_membership():
             ref = shard.pool.device_ref(s)
             assert ref is not None and ref.buf is entry["buf"]
     assert len(set(bufs)) == 3, "output buffer never flipped"
+    buf = tel.registry["sa_state_buffer_total"]
+    assert (buf.value("repack"), buf.value("hit")) == (1, 2)
     results = {r.req_id: r for r in engine.run(max_ticks=2000)}
     for req in reqs:
         solo = run_standalone(req, cfg)
         assert results[req.req_id].champion_history == solo.champion_history
 
 
-def test_membership_change_invalidates_dispatch_cache():
+@pytest.mark.parametrize("k", (1, 4))
+def test_membership_change_invalidates_dispatch_cache(k):
     """A preemption between macro-ticks repacks from host (the checkpoint
     materialized the device ref); the resumed trajectory is still
     bit-exact, so the cache-miss path reads back exactly the state the
     donated buffer held."""
     reqs = [_req(0), _req(1, objective="griewank")]
-    cfg = _cfg(k=4, n_slots=2)
+    cfg = _cfg(k=k, n_slots=2)
     engine = SAServeEngine(cfg)
     for r in reqs:
         engine.submit(r)
@@ -240,6 +258,59 @@ def test_membership_change_invalidates_dispatch_cache():
         solo = run_standalone(req, cfg)
         assert results[req.req_id].champion_history == solo.champion_history
         assert results[req.req_id].f_best == solo.f_best
+
+
+def test_k1_join_and_retire_mid_run_repack_reads_each_buffer_once():
+    """Churn at K=1 in one (dim, N) group: a request joins mid-run and
+    another retires.  Each change is a cache miss whose repack reads the
+    group's one source buffer back in a single transfer (not one per
+    slot), every other launch reuses the buffer, and every trajectory
+    stays bit-exact."""
+    reqs = [_req(0, n_chains=2 * CPS),                         # 18 levels
+            _req(1, objective="ackley", T0=6.0, rho=0.5),      # 3 levels
+            _req(2, objective="griewank")]                     # joins
+    assert reqs[1].n_levels == 3
+    cfg = _cfg(k=1, n_slots=4)
+    tel = Telemetry()
+    engine = SAServeEngine(cfg, telemetry=tel)
+    engine.submit(reqs[0])
+    engine.submit(reqs[1])
+    shard = engine.shards[0]
+    pool = shard.pool
+    buf = tel.registry["sa_state_buffer_total"]
+
+    def source():
+        (entry,) = shard.group_cache.values()
+        return entry["buf"]
+
+    engine.tick()                # 3 blocks padded to 4: packed, nothing read
+    assert (buf.value("repack"), buf.value("hit")) == (1, 0)
+    assert pool.bytes_read == 0
+    engine.submit(reqs[2])
+    src = source()
+    engine.tick()                # request 2 joins: 4 blocks, same padding
+    assert (buf.value("repack"), buf.value("hit")) == (2, 0)
+    assert pool.bytes_read == src.nbytes
+    engine.tick()                # stable: request 1 ends its ladder here
+    assert (buf.value("repack"), buf.value("hit")) == (2, 1)
+    assert [r.req_id for r in engine.results] == [1]
+    src, read0 = source(), pool.bytes_read
+    engine.tick()                # request 2's slot moves up a row
+    assert (buf.value("repack"), buf.value("hit")) == (3, 1)
+    assert pool.bytes_read - read0 == src.nbytes
+    assert tel.registry["sa_state_bytes_total"].value("d2h") == \
+        pool.bytes_read
+    results = {r.req_id: r for r in engine.run(max_ticks=2000)}
+    # Request 0 ends a level before request 2, whose block then moves to
+    # row 0: one more repack, and every other launch a hit.
+    assert buf.value("repack") == 4
+    assert buf.value("hit") == engine.group_launches - 4
+    for req in reqs:
+        solo = run_standalone(req, cfg)
+        assert results[req.req_id].champion_history == solo.champion_history
+        assert results[req.req_id].f_best == solo.f_best
+        np.testing.assert_array_equal(results[req.req_id].x_best,
+                                      solo.x_best)
 
 
 # --------------------------------------------------- ladder-level latency
