@@ -669,11 +669,14 @@ class SAServeEngine:
             for rid, si in plan.evict:
                 self._swap_out(self._shard(si), rid)
             for entry, granted_slots, si in plan.admitted:
-                self._place(self._shard(si), entry, granted_slots)
+                with self._sub("admit.place"):
+                    self._place(self._shard(si), entry, granted_slots)
 
     def _place(self, shard: EngineShard, entry: QueueEntry,
                granted_slots: int) -> None:
         tel = self.telemetry
+        if tel.enabled:
+            tel.m_placements.inc(1, str(shard.index))
         if entry.swapped is not None:       # swap-in: bit-exact resume
             job = entry.swapped.job
             job.resumed_ticks.append(self.tick_count)
@@ -1161,6 +1164,11 @@ class SAServeEngine:
             self.slot_ticks += shard.pool.n_slots
         self._admit()
         self._plan_truncations()  # finish-deadline cuts, boundary-aligned
+        if self.telemetry.enabled:
+            # A shard with a job launches its group; one without idles.
+            for shard in self.live_shards:
+                if not shard.rids.jobs:
+                    self.telemetry.m_shard_idle_ticks.inc(1, str(shard.index))
         if self.n_active == 0:
             self._retire_drained()
             self._end_tick_telemetry()

@@ -62,6 +62,8 @@ TICK_PHASES = ("schedule", "admit", "dispatch", "device_wait",
 
 #: Spans one level under a phase, named ``<phase>.<sub>``
 #: (docs/observability.md):
+#:   admit.place          — one queued job put on the shard the scheduler
+#:                          chose: slots, then init_state or restore
 #:   admit.init_state     — a new request's initial chain states (sample_x0)
 #:   admit.restore        — checkpoint / restore copies of resident blocks
 #:   dispatch.pack        — the packed state and per-block controls on host
@@ -70,9 +72,9 @@ TICK_PHASES = ("schedule", "admit", "dispatch", "device_wait",
 #:                          a compile lands here)
 #:   materialize.d2h      — np.asarray reads of the program's outputs
 #:   materialize.fold     — champion fold, history and finish checks
-TICK_SUBPHASES = ("admit.init_state", "admit.restore", "dispatch.pack",
-                  "dispatch.h2d", "dispatch.launch", "materialize.d2h",
-                  "materialize.fold")
+TICK_SUBPHASES = ("admit.place", "admit.init_state", "admit.restore",
+                  "dispatch.pack", "dispatch.h2d", "dispatch.launch",
+                  "materialize.d2h", "materialize.fold")
 
 #: Profiler annotation names, built once: ``sa.<phase>`` and
 #: ``sa.<phase>.<sub>``.
@@ -341,9 +343,10 @@ class PhaseTimer:
     host-side cost per phase.
 
     Inside the timed span it also opens a profiler annotation
-    ``sa.<phase>`` (the profiler's clock, beside the device ops) and
-    counts the backend compiles that land in the span
-    (:func:`compile_events`, into ``compiles``).
+    ``sa.<phase>`` (the profiler's clock, beside the device ops), with the
+    argument ``shard=<index>`` when the span is one shard's (``dispatch``,
+    ``device_wait``, ``materialize``), and counts the backend compiles
+    that land in the span (:func:`compile_events`, into ``compiles``).
     """
 
     #: Class-wide count of spans ever entered — the zero-overhead witness:
@@ -371,7 +374,9 @@ class PhaseTimer:
         self._t0 = self._clock()
         self._c0 = time.thread_time()
         self._n0 = compile_events()
-        self._ann = TraceAnnotation(_ANNOTATION[self._phase])
+        name = _ANNOTATION[self._phase]
+        self._ann = (TraceAnnotation(name) if self._shard is None else
+                     TraceAnnotation(name, shard=self._shard))
         return self
 
     def __exit__(self, *exc):
@@ -632,6 +637,14 @@ class Telemetry:
             "sa_state_buffer_total",
             "Launches whose device state buffer was reused (hit) or "
             "packed anew on host (repack)", ("result",))
+        self.m_placements = r.counter(
+            "sa_placements_total",
+            "Queued jobs placed on a shard (new or swapped back in)",
+            ("shard",))
+        self.m_shard_idle_ticks = r.counter(
+            "sa_shard_idle_ticks_total",
+            "Ticks in which a live shard held no job and launched nothing",
+            ("shard",))
 
     # -- hooks the engine calls (every one a no-op on NullTelemetry) --
     def make_phase_timer(self, clock) -> PhaseTimer:

@@ -439,8 +439,9 @@ def test_subspans_enter_and_stay_out_of_the_phase_sums():
     assert SubPhaseTimer.spans_entered > subs_before     # the witness is live
     sub = tel.registry["sa_tick_subphase_seconds_total"]
     got = {name for (name,) in sub.series}
-    assert got == {"admit.init_state", "dispatch.pack", "dispatch.h2d",
-                   "dispatch.launch", "materialize.d2h", "materialize.fold"}
+    assert got == {"admit.place", "admit.init_state", "dispatch.pack",
+                   "dispatch.h2d", "dispatch.launch", "materialize.d2h",
+                   "materialize.fold"}
     assert got <= set(TICK_SUBPHASES)
     # The phase histogram holds the six phases and nothing else.
     phases = {p for (p,) in tel.registry["sa_tick_phase_seconds"].series}

@@ -54,7 +54,13 @@ def _compile_text(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("dim", [8, 512])
+#: Widths of the paper's Table 8 suite as the server runs it
+#: (``bench/configs/suite-table8-fleet4.json``): each width that is not a
+#: power of two, and 4, 8 and 512.
+SUITE_DIMS = [4, 8, 10, 30, 100, 200, 400, 512]
+
+
+@pytest.mark.parametrize("dim", SUITE_DIMS)
 @pytest.mark.parametrize("variant", ["delta", "full"])
 def test_metropolis_sweep_compiles(one_chip, variant, dim):
     """The engine's form: runtime objective id, level cursor and per-chain
