@@ -2,13 +2,14 @@
 
 A *family* owns everything about a problem class that the representation
 determines — the chain-state dtype and per-chain shape, the deterministic
-initial-state sampler, the known optimum lookup, and which sweep kernel the
-engine dispatches — while the serving machinery above it (slots, scheduler,
-engine tick loop, exchange operators, checkpoint/restore) stays family-
-agnostic.  A request names its family (``SARequest.family``) and an
-objective *within* that family; dispatch groups are keyed by
-``(family, dim, N)``, so heterogeneous families co-batch in one fleet with
-one compiled device program per family per shape.
+initial-state sampler, the known optimum lookup, the per-block operands a
+packed group hands its sweep, and which sweep kernel runs — while the
+serving machinery above it (slots, scheduler, engine tick loop, exchange
+operators, checkpoint/restore) stays family-agnostic.  A request names its
+family (``SARequest.family``) and an objective *within* that family;
+dispatch groups are keyed by ``(family, dim, N)``, so heterogeneous
+families co-batch in one fleet with one compiled device program per family
+per shape.
 
 Registered families
 -------------------
@@ -27,19 +28,22 @@ across preemption, migration, drain, resize and macro-K fusion.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.kernels import objective_math as om
+from repro.kernels import ops
 from repro.objectives import qap
 
 FAMILY_CONTINUOUS = "continuous"
 FAMILY_PERMUTATION = "permutation"
 
 #: Known optima of the continuous registry objectives, by name (Schwefel is
-#: the paper's normalized form, so its optimum is dim-free).  The engine's
-#: kid-keyed ``F_OPT`` is derived from this — one source of truth.
+#: the paper's normalized form, so its optimum is dim-free).  A continuous
+#: request may set ``target_error`` only on an objective listed here
+#: (``SAServeEngine.submit`` checks it through :meth:`ProblemFamily.f_opt`).
 F_OPT_BY_NAME = {
     "schwefel": -418.982887,
     "rastrigin": 0.0,
@@ -51,13 +55,21 @@ F_OPT_BY_NAME = {
 
 
 class ProblemFamily:
-    """One problem representation: state layout + samplers + optima.
+    """One problem representation: state layout + samplers + optima +
+    its sweep.
 
     Subclasses are stateless singletons; every method takes the request so
     a family never caches per-tenant data.  ``validate`` runs inside
     ``SARequest.__post_init__`` — family-incompatible fields fail eagerly
-    with a typed ValueError at construction, never mid-tick.
+    with a typed ValueError at construction, never mid-tick.  A family is
+    a static pytree (no leaves): handed to a jitted program as an
+    ordinary argument, it selects that program's sweep and is part of its
+    compiled signature, so each family compiles its own program.
     """
+
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        jax.tree_util.register_static(cls)
 
     #: family name — the ``SARequest.family`` value and dispatch-group key
     name: str = ""
@@ -79,6 +91,22 @@ class ProblemFamily:
 
     def f_opt(self, req) -> Optional[float]:
         """Known optimum for ``req.objective`` (None if unregistered)."""
+        raise NotImplementedError
+
+    def block_operands(self, reqs: Sequence, n_padded: int) -> tuple:
+        """The per-block runtime operands of one packed group's sweep:
+        ``reqs[b]`` is the request of block ``b``; pad blocks, up to
+        ``n_padded``, repeat block 0's.  Runtime inputs, so the group's
+        objectives or instances never cause a recompile."""
+        raise NotImplementedError
+
+    def sweep(self, x, operands: tuple, T, seeds, step0s, chain_base, *,
+              n_steps: int, blk: int, variant: str, use_pallas: bool,
+              interpret: bool, live, T_chain):
+        """One N-step sweep of every block of a packed group (traced
+        inside the engine's group program): ``operands`` as
+        :meth:`block_operands` packed them, the per-block controls of
+        ``ops.metropolis_sweep_slots``.  Returns ``(x, fx)``."""
         raise NotImplementedError
 
 
@@ -105,6 +133,20 @@ class ContinuousFamily(ProblemFamily):
 
     def f_opt(self, req) -> Optional[float]:
         return F_OPT_BY_NAME.get(req.objective)
+
+    def block_operands(self, reqs: Sequence, n_padded: int) -> tuple:
+        kid = np.full((n_padded,), om.KID_BY_NAME[reqs[0].objective],
+                      np.int32)
+        kid[:len(reqs)] = [om.KID_BY_NAME[r.objective] for r in reqs]
+        return (kid,)
+
+    def sweep(self, x, operands, T, seeds, step0s, chain_base, *, n_steps,
+              blk, variant, use_pallas, interpret, live, T_chain):
+        (kid,) = operands
+        return ops.metropolis_sweep_slots(
+            x, kid, T, seeds, step0s, chain_base, n_steps=n_steps, blk=blk,
+            variant=variant, use_pallas=use_pallas, interpret=interpret,
+            live=live, T_chain=T_chain)
 
 
 class PermutationFamily(ProblemFamily):
@@ -155,6 +197,27 @@ class PermutationFamily(ProblemFamily):
 
     def f_opt(self, req) -> Optional[float]:
         return float(qap.INSTANCES[req.objective].best_known)
+
+    def block_operands(self, reqs: Sequence, n_padded: int) -> tuple:
+        # Flow and distance packed (n_padded * n, n): block b reads rows
+        # [b*n, (b+1)*n), so mixed instances co-batch in one program.
+        n = reqs[0].dim
+        F = np.empty((n_padded * n, n), np.float32)
+        D = np.empty((n_padded * n, n), np.float32)
+        for b in range(n_padded):
+            inst = qap.get(reqs[b if b < len(reqs) else 0].objective)
+            F[b * n:(b + 1) * n] = inst.F
+            D[b * n:(b + 1) * n] = inst.D
+        return F, D
+
+    def sweep(self, x, operands, T, seeds, step0s, chain_base, *, n_steps,
+              blk, variant, use_pallas, interpret, live, T_chain):
+        # Always delta-evaluated (bitwise a full evaluation), so no
+        # variant; no T_chain, since a permutation request is never PT.
+        F, D = operands
+        return ops.qap_sweep_slots(
+            x, F, D, T, seeds, step0s, chain_base, n_steps=n_steps, blk=blk,
+            use_pallas=use_pallas, interpret=interpret, live=live)
 
 
 CONTINUOUS = ContinuousFamily()

@@ -61,8 +61,7 @@ Or from the shell::
 """
 from repro.service.arrivals import ArrivalProcess, latency_summary
 from repro.service.autoscaler import Autoscaler, AutoscalerConfig
-from repro.service.engine import (EngineConfig, SAServeEngine, F_OPT,
-                                  run_standalone)
+from repro.service.engine import EngineConfig, SAServeEngine, run_standalone
 from repro.service.request import (OVERLOAD_POLICIES, RequestResult,
                                    SARequest, SERVABLE, TERMINAL_REASONS)
 from repro.service.scheduler import (AdmissionPlan, AdmissionScheduler,
@@ -75,7 +74,7 @@ from repro.service.telemetry import (EventLog, MetricsRegistry, PhaseTimer,
 from repro.service.trace import TraceBuilder, validate_trace
 
 __all__ = [
-    "EngineConfig", "SAServeEngine", "run_standalone", "F_OPT",
+    "EngineConfig", "SAServeEngine", "run_standalone",
     "SARequest", "RequestResult", "SERVABLE", "OVERLOAD_POLICIES",
     "TERMINAL_REASONS",
     "AdmissionScheduler", "AdmissionPlan", "QueueEntry", "SchedulerConfig",

@@ -23,7 +23,8 @@ Invariants
   Admission, preemption, migration and fleet ops land only on macro-tick
   boundaries (the top of ``tick()``); within a macro-tick the K levels —
   including the per-level champion exchange — run fused in one device
-  program with donated ping-pong state buffers (``_group_tick_fused``).
+  program with donated ping-pong state buffers (``_group_tick``, the one
+  group program at every K).
 * **kid is runtime**: per-slot *objective id, temperature, RNG seed, step
   cursor and chain base* are runtime arrays threaded down to the kernel
   (one SMEM entry per block, indexed by ``program_id``) — none of them can
@@ -96,7 +97,6 @@ import numpy as np
 from jax import lax
 
 from repro.core import exchange as exch
-from repro.kernels import objective_math as om
 from repro.kernels import ops
 from repro.objectives import families as fam_mod
 from repro.service.request import RequestResult, SARequest
@@ -113,19 +113,6 @@ from repro.service.telemetry import NULL as NULL_TELEMETRY
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable",
     category=UserWarning)
-
-#: Known optima of the servable *continuous* (registry) objectives, keyed
-#: by kernel id — derived from the family layer's name-keyed table so the
-#: values live in exactly one place (objectives/families.py).  Schwefel is
-#: the paper's normalized form, so its optimum is dim-free.  A continuous
-#: request may only set ``target_error`` on an objective listed here —
-#: :meth:`SAServeEngine.submit` validates it eagerly (a typed ValueError at
-#: the frontend) instead of letting a KeyError wedge a slot mid-tick.
-#: Permutation (QAP) requests never consult this dict: every registered
-#: instance carries a verifiable ``best_known`` (``req.f_opt``).
-F_OPT = {om.KID_BY_NAME[name]: v
-         for name, v in fam_mod.F_OPT_BY_NAME.items()}
-
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
@@ -177,60 +164,25 @@ def _chain_controls(T_blk, seed_blk, base_blk, lvl0, mcode, t_rung, blk: int):
 @partial(jax.jit, static_argnames=("n_steps", "blk", "variant",
                                    "use_pallas", "interpret", "num_segments"),
          donate_argnums=(0,))
-def _group_tick(x, kid_blk, T_blk, seed_blk, step0_blk, base_blk, lvl0_blk,
-                dbeta_blk, seg, adopt, mcode, t_rung, partner, pairlo,
-                seg_lo, seg_hi, *, n_steps: int, blk: int, variant: str,
-                use_pallas: bool, interpret: bool, num_segments: int):
-    """One temperature level for one dispatch group, on device.
+def _group_tick(x, family, fam_ops, T_lvls, dbeta_lvls, seed_blk, step0_blk,
+                base_blk, levels_blk, lvl0_blk, seg, adopt, mcode, t_rung,
+                partner, pairlo, seg_lo, seg_hi, *, n_steps: int, blk: int,
+                variant: str, use_pallas: bool, interpret: bool,
+                num_segments: int):
+    """``k`` temperature levels of one dispatch group, in one device program.
 
-    Sweep every block on its own objective (``kid_blk`` is a runtime
-    input — mixed-objective groups share one lowering) at its own
-    temperature — per *chain* when the block belongs to a parallel-
-    tempering tenant (``t_rung``) — then the composite segmented exchange
+    An on-device ``fori_loop`` over ``k`` iterations of [one-level sweep +
+    composite segmented exchange], ``k = T_lvls.shape[0]`` (the engine's
+    ``macro_k``; a one-trip loop at K=1).  Each level sweeps every block on
+    its own problem at its own temperature — per *chain* when the block
+    belongs to a parallel-tempering tenant (``t_rung``) — with the sweep of
+    ``family`` (objectives/families.py) on its per-block runtime operands
+    ``fam_ops``, so mixed objectives or instances of one family share one
+    lowering.  Then the composite segmented exchange
     (core/exchange.serving_exchange): champion reduce, sync/sos adoption,
-    PT even/odd swap, PA resample, each masked per workload class so a
-    plain-SA-only batch is bitwise the classic path.  The champion is
-    returned for every segment either way so the host can fold
-    best-so-far.
-
-    The three stages run under ``jax.named_scope``s — ``sa.controls``,
-    ``sa.sweep``, ``sa.exchange`` — that name their device ops in a
-    profiler trace (metadata only: the program is the same).  Every
-    group program uses the same three.  ``x`` is **donated**, as in the
-    fused program: the state stays on the device between levels while the
-    group's membership is stable.
-    """
-    with jax.named_scope("sa.controls"):
-        sched, T_chain, seed_c, cidx, lvl_abs = _chain_controls(
-            T_blk, seed_blk, base_blk, lvl0_blk, mcode, t_rung, blk)
-        dbeta_c = jnp.repeat(dbeta_blk, blk)
-    with jax.named_scope("sa.sweep"):
-        x, fx = ops.metropolis_sweep_slots(
-            x, kid_blk, T_blk, seed_blk, step0_blk, base_blk,
-            n_steps=n_steps, blk=blk, variant=variant, use_pallas=use_pallas,
-            interpret=interpret, T_chain=T_chain)
-    live = jnp.ones(fx.shape, bool)
-    with jax.named_scope("sa.exchange"):
-        return exch.serving_exchange(
-            x, fx, seg, num_segments, adopt, mcode, t_rung, sched, partner,
-            pairlo, seg_lo, seg_hi, dbeta_c, seed_c, cidx, lvl_abs, live)
-
-
-@partial(jax.jit, static_argnames=("k", "n_steps", "blk", "variant",
-                                   "use_pallas", "interpret",
-                                   "num_segments"),
-         donate_argnums=(0,))
-def _group_tick_fused(x, kid_blk, T_lvls, seed_blk, step0_blk, base_blk,
-                      levels_blk, lvl0_blk, dbeta_lvls, seg, adopt, mcode,
-                      t_rung, partner2, pairlo2, seg_lo, seg_hi, *, k: int,
-                      n_steps: int, blk: int, variant: str, use_pallas: bool,
-                      interpret: bool, num_segments: int):
-    """K temperature levels for one dispatch group, in one device program.
-
-    The macro-tick: an on-device ``fori_loop`` over ``k`` iterations of
-    [one-level sweep + composite segmented exchange] — exactly the K=1
-    ``_group_tick`` body K times, so each level's floating-point stream is
-    identical to K separate dispatches.  Per-level controls:
+    PT even/odd swap, PA resample, each masked per workload class.  Each
+    level's floating-point stream is that of a separate one-level launch.
+    Per-level controls:
 
     * ``T_lvls`` is ``(k, n_blocks)`` — each block's host-precomputed
       temperature ladder slice, one SMEM row per level — and
@@ -238,25 +190,32 @@ def _group_tick_fused(x, kid_blk, T_lvls, seed_blk, step0_blk, base_blk,
     * level ``i`` sweeps with RNG step cursor ``step0 + i*n_steps`` at
       absolute ladder level ``lvl0_blk + i`` (the exchange RNG counter);
     * ``levels_blk`` is the per-slot level cursor: blocks whose request
-      has fewer than ``k`` planned levels go *dead* (``live = i <
-      levels_blk``) — the kernel masks their accepts so state passes
-      through bit-exactly, and the per-class masks keep their chains out
-      of every exchange stage;
-    * ``partner2`` / ``pairlo2`` are ``(2, chains)``: row ``i % 2`` holds
-      each PT chain's swap partner for that level's even/odd parity
-      (host-precomputed from its own job's absolute level).
+      has fewer than ``k`` planned levels, and pad blocks, go *dead*
+      (``live = i < levels_blk``) — the kernel masks their accepts so
+      state passes through bit-exactly, and the per-class masks keep
+      their chains out of every exchange stage;
+    * ``partner`` / ``pairlo`` are ``(rows, chains)``, ``rows = min(k,
+      2)``: row ``i % rows`` holds each PT chain's swap partner for that
+      level's even/odd parity (host-precomputed from its own job's
+      absolute level).
 
-    Per-level champions come back stacked — ``(k, num_segments)`` values
-    and ``(k, num_segments, dim)`` states — for the host to fold level by
-    level (truncating at early finishes), plus ``fx_keep``: each chain's
-    post-exchange objective value at its *last live* level (dead
-    iterations re-derive f(x) bitwise differently, so the live value is
-    carried, not recomputed) — the population-annealing ESS controller
-    reads it at the boundary.  ``x`` is **donated**: the engine's double
-    buffer ping-pongs between launches, so chain state never round-trips
-    to host while a group's membership is stable.
+    Per-level champions come back stacked — ``(k, num_segments)`` float32
+    values and ``(k, num_segments, dim)`` states of the family's dtype —
+    for the host to fold level by level (truncating at early finishes),
+    plus ``fx_keep``: each chain's post-exchange objective value at its
+    *last live* level (dead iterations re-derive f(x) bitwise differently,
+    so the live value is carried, not recomputed) — the population-
+    annealing ESS controller reads it at the boundary.
+
+    The stages run under ``jax.named_scope``s — ``sa.controls``,
+    ``sa.sweep``, ``sa.exchange`` — that name their device ops in a
+    profiler trace (metadata only: the program is the same).  ``x`` is
+    **donated**: the engine's double buffer ping-pongs between launches,
+    so chain state never round-trips to host while a group's membership
+    is stable.
     """
-    dim = x.shape[1]
+    k, dim = T_lvls.shape[0], x.shape[1]
+    rows = partner.shape[0]
 
     def body(i, carry):
         x, fx_keep, fb_all, xb_all = carry
@@ -270,14 +229,14 @@ def _group_tick_fused(x, kid_blk, T_lvls, seed_blk, step0_blk, base_blk,
                 T_i, seed_blk, base_blk, lvl0_blk + i.astype(jnp.uint32),
                 mcode, t_rung, blk)
             live_c = jnp.repeat(live, blk)
-            prt = lax.dynamic_index_in_dim(partner2, i % 2, 0,
+            prt = lax.dynamic_index_in_dim(partner, i % rows, 0,
                                            keepdims=False)
-            plo = lax.dynamic_index_in_dim(pairlo2, i % 2, 0,
+            plo = lax.dynamic_index_in_dim(pairlo, i % rows, 0,
                                            keepdims=False)
             dbeta_c = jnp.repeat(db_i, blk)
         with jax.named_scope("sa.sweep"):
-            x, fx = ops.metropolis_sweep_slots(
-                x, kid_blk, T_i, seed_blk, step0_i, base_blk,
+            x, fx = family.sweep(
+                x, fam_ops, T_i, seed_blk, step0_i, base_blk,
                 n_steps=n_steps, blk=blk, variant=variant,
                 use_pallas=use_pallas, interpret=interpret, live=live,
                 T_chain=T_chain)
@@ -285,98 +244,6 @@ def _group_tick_fused(x, kid_blk, T_lvls, seed_blk, step0_blk, base_blk,
             x, fx, xb, fb = exch.serving_exchange(
                 x, fx, seg, num_segments, adopt, mcode, t_rung, sched, prt,
                 plo, seg_lo, seg_hi, dbeta_c, seed_c, cidx, lvl_abs, live_c)
-        fx_keep = jnp.where(live_c, fx, fx_keep)
-        return x, fx_keep, fb_all.at[i].set(fb), xb_all.at[i].set(xb)
-
-    fb0 = jnp.full((k, num_segments), jnp.inf, x.dtype)
-    xb0 = jnp.zeros((k, num_segments, dim), x.dtype)
-    fx0 = jnp.zeros((x.shape[0],), x.dtype)
-    return lax.fori_loop(0, k, body, (x, fx0, fb0, xb0))
-
-
-@partial(jax.jit, static_argnames=("n_steps", "blk", "use_pallas",
-                                   "interpret", "num_segments"),
-         donate_argnums=(0,))
-def _group_tick_qap(x, F_blk, D_blk, T_blk, seed_blk, step0_blk, base_blk,
-                    lvl0_blk, seg, adopt, mcode, t_rung, partner, pairlo,
-                    seg_lo, seg_hi, *, n_steps: int, blk: int,
-                    use_pallas: bool, interpret: bool, num_segments: int):
-    """One temperature level for one *permutation-family* dispatch group.
-
-    The QAP counterpart of :func:`_group_tick`: the same control layout
-    and the same composite segmented exchange (dtype-agnostic over the
-    chain states, so int32 permutations ride it unchanged), but the sweep
-    is the pairwise-exchange QAP kernel and the per-block runtime operands
-    are the flow/distance matrices (packed ``(n_blocks*n, n)``) instead of
-    an objective id.  Chain states ``x`` are int32; objective values stay
-    float32 (exact for the integer-valued instances).  No ``variant``/
-    ``dbeta``: the QAP sweep is always delta-evaluated (bitwise equal to a
-    full evaluation) and permutation requests are method-'sa' only, so the
-    PA reweighting increment is identically zero.  A separate jit (typed
-    on int32 x) naturally pins one compiled program per family.  ``x``
-    is donated, as in :func:`_group_tick`.
-    """
-    with jax.named_scope("sa.controls"):
-        sched, T_chain, seed_c, cidx, lvl_abs = _chain_controls(
-            T_blk, seed_blk, base_blk, lvl0_blk, mcode, t_rung, blk)
-    with jax.named_scope("sa.sweep"):
-        x, fx = ops.qap_sweep_slots(
-            x, F_blk, D_blk, T_blk, seed_blk, step0_blk, base_blk,
-            n_steps=n_steps, blk=blk, use_pallas=use_pallas,
-            interpret=interpret)
-    live = jnp.ones(fx.shape, bool)
-    with jax.named_scope("sa.exchange"):
-        return exch.serving_exchange(
-            x, fx, seg, num_segments, adopt, mcode, t_rung, sched, partner,
-            pairlo, seg_lo, seg_hi, jnp.zeros_like(fx), seed_c, cidx,
-            lvl_abs, live)
-
-
-@partial(jax.jit, static_argnames=("k", "n_steps", "blk", "use_pallas",
-                                   "interpret", "num_segments"),
-         donate_argnums=(0,))
-def _group_tick_qap_fused(x, F_blk, D_blk, T_lvls, seed_blk, step0_blk,
-                          base_blk, levels_blk, lvl0_blk, seg, adopt, mcode,
-                          t_rung, partner2, pairlo2, seg_lo, seg_hi, *,
-                          k: int, n_steps: int, blk: int, use_pallas: bool,
-                          interpret: bool, num_segments: int):
-    """K temperature levels for one permutation-family group, fused.
-
-    Mirrors :func:`_group_tick_fused` level by level — same live-cursor
-    masking, per-level champion stacks and donated ping-pong state buffer
-    — with the QAP sweep in place of the Metropolis one.  The champion
-    carry is typed explicitly (float32 values, int32 states): the
-    continuous path types both off ``x.dtype``, which is exactly what an
-    int32 state buffer must not do.  ``fx_keep`` is carried for interface
-    parity (the PA controller never reads it here — permutation requests
-    are method-'sa' only).
-    """
-    dim = x.shape[1]
-
-    def body(i, carry):
-        x, fx_keep, fb_all, xb_all = carry
-        live = i < levels_blk                       # (n_blocks,) cursor
-        with jax.named_scope("sa.controls"):
-            T_i = lax.dynamic_index_in_dim(T_lvls, i, 0, keepdims=False)
-            step0_i = step0_blk + jnp.uint32(n_steps) * i.astype(jnp.uint32)
-            sched, T_chain, seed_c, cidx, lvl_abs = _chain_controls(
-                T_i, seed_blk, base_blk, lvl0_blk + i.astype(jnp.uint32),
-                mcode, t_rung, blk)
-            live_c = jnp.repeat(live, blk)
-            prt = lax.dynamic_index_in_dim(partner2, i % 2, 0,
-                                           keepdims=False)
-            plo = lax.dynamic_index_in_dim(pairlo2, i % 2, 0,
-                                           keepdims=False)
-        with jax.named_scope("sa.sweep"):
-            x, fx = ops.qap_sweep_slots(
-                x, F_blk, D_blk, T_i, seed_blk, step0_i, base_blk,
-                n_steps=n_steps, blk=blk, use_pallas=use_pallas,
-                interpret=interpret, live=live)
-        with jax.named_scope("sa.exchange"):
-            x, fx, xb, fb = exch.serving_exchange(
-                x, fx, seg, num_segments, adopt, mcode, t_rung, sched, prt,
-                plo, seg_lo, seg_hi, jnp.zeros_like(fx), seed_c, cidx,
-                lvl_abs, live_c)
         fx_keep = jnp.where(live_c, fx, fx_keep)
         return x, fx_keep, fb_all.at[i].set(fb), xb_all.at[i].set(xb)
 
@@ -512,17 +379,14 @@ class SAServeEngine:
                 f"request {req.req_id} needs {need} slots > the per-shard "
                 f"pool of {self.cfg.n_slots}; requests never span shards — "
                 "lower n_chains or grow n_slots")
-        if (req.target_error is not None
-                and req.family == fam_mod.FAMILY_CONTINUOUS
-                and req.kid not in F_OPT):
-            # Validate here, not mid-tick: an unguarded F_OPT lookup in the
-            # finish check would raise KeyError after admission and wedge
-            # the request's slots for good.  Permutation requests skip the
-            # check: every registered QAP instance carries a best_known.
+        if req.target_error is not None and req.f_opt is None:
+            # Validate here, not mid-tick: a job whose finish check has no
+            # optimum to compare against would anneal to the end of its
+            # ladder while its client waits for a target stop.
             raise ValueError(
                 f"request {req.req_id} sets target_error but objective "
-                f"{req.objective!r} has no registered optimum in "
-                "engine.F_OPT; register one or drop target_error")
+                f"{req.objective!r} has no registered optimum in its "
+                f"family ({req.family}); register one or drop target_error")
         if (req.req_id in self._submit_info
                 or any(job.req.req_id == req.req_id
                        for _, job in self._iter_jobs())
@@ -1119,7 +983,7 @@ class SAServeEngine:
     # ---------------------------------------------------------------- tick
     def tick(self) -> None:
         """Admit, then advance every active slot by ``macro_k`` temperature
-        levels in one fused dispatch per group (one level when K=1).
+        levels in one dispatch of ``_group_tick`` per group.
 
         Two passes over the shards: *launch* every ``(shard, dim, N)``
         group's device program first (JAX dispatch is asynchronous, so
@@ -1133,7 +997,7 @@ class SAServeEngine:
         Macro-ticks (K>1): the top of a tick is a **macro-tick boundary**
         — scripted ops, admission, preemption, migration and rebalancing
         all land here, then every group runs K ladder levels on device
-        with per-level champion exchange (``_group_tick_fused``) before
+        with per-level champion exchange (``_group_tick``) before
         the next boundary.  ``tick_count`` stays on the *ladder-level*
         clock: an active macro-tick advances it by the most levels any
         job consumed (K mid-flight, less only when every job terminated
@@ -1144,8 +1008,8 @@ class SAServeEngine:
         monotonic span (``schedule / admit / dispatch / device_wait /
         materialize / retire``), and an explicit ``block_until_ready``
         fence per shard separates host-side launch cost (``dispatch``)
-        from device compute (``device_wait``) — at K>1 the fence simply
-        covers the whole fused K-level program.  The fence changes *when*
+        from device compute (``device_wait``) — the fence covers the whole
+        K-level program.  The fence changes *when*
         the host observes completion, never what was computed: the
         launch-all-then-collect order is preserved, so telemetry is
         bit-exact (tests assert it).
@@ -1174,7 +1038,6 @@ class SAServeEngine:
             self._end_tick_telemetry()
             self.tick_count += 1
             return
-        K = self.cfg.macro_k
         launches = []
         for shard in self.shards:
             # Dispatch groups are keyed by shape alone — (family, dim, N)
@@ -1190,10 +1053,7 @@ class SAServeEngine:
             with pt("dispatch", shard.index):
                 for (family, dim, n_steps), jobs in sorted(groups.items()):
                     launches.append(
-                        self._launch_group(shard, family, dim, n_steps, jobs)
-                        if K == 1 else
-                        self._launch_group_fused(shard, family, dim,
-                                                 n_steps, jobs))
+                        self._launch_group(shard, family, dim, n_steps, jobs))
                     self.group_launches += 1
                 # A group that ran nothing this tick keeps no device buffer.
                 for key in shard.group_cache.keys() - groups.keys():
@@ -1211,12 +1071,9 @@ class SAServeEngine:
         advance = 1
         for launch in launches:
             with pt("materialize", launch[0].index):
-                if K == 1:
-                    finished.extend(self._collect_group(*launch))
-                else:
-                    got, levels = self._collect_group_fused(*launch)
-                    finished.extend(got)
-                    advance = max(advance, levels)
+                got, levels = self._collect_group(*launch)
+                finished.extend(got)
+                advance = max(advance, levels)
         if advance > 1:
             # The macro-tick held the fleet's slots for `advance` ladder
             # levels (admission waits for the next boundary), so occupancy
@@ -1230,7 +1087,7 @@ class SAServeEngine:
                 self.slot_ticks += shard.pool.n_slots * (advance - 1)
         with pt("retire"):
             for shard, job, reason, finish_tick in finished:
-                self._retire(shard, job, reason, finish_tick=finish_tick)
+                self._retire(shard, job, reason, finish_tick)
         # A draining shard whose last job just retired (or evacuated) is
         # removed now, so a run that ends this tick leaves no zombie
         # shards behind.
@@ -1249,71 +1106,28 @@ class SAServeEngine:
                                     self.n_active, levels=levels)
 
     def _collect_group(self, shard: EngineShard, n_steps: int,
-                       jobs: List[ActiveJob], outs):
-        """Fold one group's champions and advance its jobs one level;
-        returns the finished ``(shard, job, reason, finish_tick)`` tuples
-        for the caller's retire pass (slot frees can wait: admission
-        happens at the top of the next tick, so deferring the release is
-        equivalent)."""
-        tel = self.telemetry
-        sub = self._sub
-        with sub("materialize.d2h", shard.index):
-            # Only what the host folds: the chain state stays on the
-            # device (the pool holds refs into outs[0], set at launch).
-            xb, fb = np.asarray(outs[2]), np.asarray(outs[3])
-            fxh = (np.asarray(outs[1])
-                   if any(j.req.pa_ess_ratio > 0 for j in jobs) else None)
-        finished = []
-        row0 = 0
-        with sub("materialize.fold", shard.index):
-            for job in jobs:
-                rows = slice(row0, row0 + job.granted_chains)
-                row0 += job.granted_chains
-                f = float(fb[job.rid])
-                if f < job.best_f:
-                    job.best_f = f
-                    job.best_x = xb[job.rid].copy()
-                if job.first_tick < 0:
-                    job.first_tick = self.tick_count
-                    job.first_tick_wall = self._now()
-                self.sweeps_done += len(job.slots)
-                shard.sweeps_done += len(job.slots)
-                job.level += 1
-                job.steps_done += n_steps
-                job.evals += n_steps * job.granted_chains
-                job.T *= job.req.rho
-                job.history.append(job.best_f)   # champion trajectory/level
-                if tel.enabled:
-                    tel.tenant_slot_ticks(job.req.req_id, len(job.slots))
-                reason = self._finish_reason(job)
-                if reason is not None:
-                    finished.append((shard, job, reason, self.tick_count))
-                elif fxh is not None:
-                    self._maybe_pa_shrink(shard, job, fxh[rows])
-        return finished
-
-    def _collect_group_fused(self, shard: EngineShard, n_steps: int,
-                             jobs: List[ActiveJob], outs,
-                             planned: Dict[int, int]):
-        """Fold one fused macro-tick's results on host.
+                       jobs: List[ActiveJob], outs, planned: Dict[int, int]):
+        """Fold one group's launch on host.
 
         Only the per-level champion stacks transfer to host (small); chain
         state stays device-resident — the pool already holds refs into
-        ``outs[0]`` (set at launch).  Each job's levels are counted
-        exactly as K=1 collects would: fold champion, advance the cursors,
-        append history, check the finish reason — stopping at the first
-        terminal level.  A target stop mid-macro-tick therefore truncates
-        the job identically to the K=1 engine; the extra device levels it
-        already swept are discarded with its slots at retire.  Budget and
-        ladder stops cannot fire early: the launch planned at most that
-        many levels.  ``finish_tick`` is the ladder-level clock value of
-        the finishing level — boundary + counted − 1 — so lifecycle
-        latencies keep level units at any K.
+        ``outs[0]`` (set at launch).  Each job's levels are counted one by
+        one: fold champion, advance the cursors, append history, check the
+        finish reason — stopping at the first terminal level.  A target
+        stop mid-macro-tick therefore truncates the job exactly where a
+        one-level tick would; the extra device levels it already swept
+        are discarded with its slots at retire.  Budget and ladder stops
+        cannot fire early: the launch planned at most that many levels.
+        ``finish_tick`` is the ladder-level clock value of the finishing
+        level — boundary + counted − 1 — so lifecycle latencies keep
+        level units at any K.
 
-        Returns ``(finished, max_counted)``: the terminal tuples plus the
-        most levels any job in this group consumed — the caller advances
-        the tick clock by the fleet-wide max, keeping ``tick_count`` equal
-        to the K=1 engine's at every boundary.
+        Returns ``(finished, max_counted)``: the terminal ``(shard, job,
+        reason, finish_tick)`` tuples for the caller's retire pass (slot
+        frees can wait: admission happens at the top of the next tick)
+        plus the most levels any job in this group consumed — the caller
+        advances the tick clock by the fleet-wide max, keeping
+        ``tick_count`` equal to the one-level engine's at every boundary.
         """
         tel = self.telemetry
         boundary = self.tick_count
@@ -1371,10 +1185,9 @@ class SAServeEngine:
         from the job's first packed row.  Defaults are the identity for
         every stage of the composite exchange: plain code, self-partner,
         self-range — pad blocks and plain-SA tenants pass through bitwise
-        untouched.  ``n_parities`` rows of partners are built (1 for the
-        K=1 path, 2 for the fused path's even/odd alternation); row ``j``
-        holds each chain's partner at the parity of its own job's
-        ``level + j``.
+        untouched.  ``n_parities`` rows of partners are built (``min(K,
+        2)``: the even/odd alternation needs no more); row ``j`` holds each
+        chain's partner at the parity of its own job's ``level + j``.
         """
         cps = self.cfg.chains_per_slot
         nc = n_padded * cps
@@ -1401,37 +1214,40 @@ class SAServeEngine:
             row0 += n
         return mcode, t_rung, partner, pairlo, seg_lo, seg_hi
 
-    def _launch_group_fused(self, shard: EngineShard, family: str, dim: int,
-                            n_steps: int, jobs: List[ActiveJob]):
+    def _launch_group(self, shard: EngineShard, family: str, dim: int,
+                      n_steps: int, jobs: List[ActiveJob]):
         """Pack the group's controls, reuse (or rebuild) its device state
-        buffer, and launch one fused K-level program (async).
+        buffer, and launch one ``macro_k``-level program (async); returns
+        the collect-pass arguments.
 
-        ``family`` picks the device program and the packing details: the
-        continuous Metropolis program takes per-block objective ids and PA
-        increments; the permutation (QAP) program takes per-block
-        flow/distance operands and int32 chain state.  Everything else —
-        level planning, control layout, the double buffer, the collect
-        contract — is family-agnostic.
+        ``family`` names the group's problem family
+        (objectives/families.py), which packs its per-block sweep operands
+        and supplies the sweep; everything else — level planning, control
+        layout, the double buffer, the collect contract — is
+        family-agnostic.
 
         Per-job level planning: ``min(K, remaining ladder, remaining eval
         budget)`` — computed on host so budget/ladder finishes land on
-        exactly the K=1 level, never overshooting.  Temperatures for the
-        K levels are iterated in float64 on host (``t *= rho``, matching
-        the K=1 cursor update) and threaded as a ``(K, n_blocks)`` SMEM
-        array.
+        exactly the level a one-level tick would stop at, never
+        overshooting.  Temperatures for the K levels are iterated in
+        float64 on host (``t *= rho``, the collect's cursor update) and
+        threaded as a ``(K, n_blocks)`` SMEM array.
 
         The chain state comes from :meth:`_group_state` (the double
-        buffer, shared with the K=1 path) and stays on the device after
-        the launch (:meth:`_keep_group_state`).
+        buffer) and stays on the device after the launch
+        (:meth:`_keep_group_state`).
         """
         cps = self.cfg.chains_per_slot
         K = self.cfg.macro_k
-        is_qap = family == fam_mod.FAMILY_PERMUTATION
+        fam = fam_mod.get_family(family)
         tel = self.telemetry
         sub = self._sub
         slot_list: List[Tuple[int, ActiveJob]] = [
             (s, job) for job in jobs for s in job.slots]
         n_blocks = len(slot_list)
+        # Pad to a power of two of blocks so the number of compiled
+        # signatures per (family, dim, N) is O(log n_slots), not
+        # O(n_slots).
         n_padded = 1
         while n_padded < n_blocks:
             n_padded *= 2
@@ -1446,13 +1262,6 @@ class SAServeEngine:
                     p = min(p, max(1, -(-remaining // per_level)))
                 planned[job.rid] = p
 
-            kid_blk = np.empty((n_padded,), np.int32)
-            if is_qap:
-                # Per-block instance operands, packed (n_padded * dim,
-                # dim): block b reads rows [b*dim, (b+1)*dim).  Runtime
-                # inputs, so mixed instances co-batch without recompiling.
-                F_blk = np.empty((n_padded * dim, dim), np.float32)
-                D_blk = np.empty((n_padded * dim, dim), np.float32)
             T_lvls = np.empty((K, n_padded), np.float32)
             dbeta_lvls = np.zeros((K, n_padded), np.float32)
             seed_blk = np.empty((n_padded,), np.uint32)
@@ -1463,16 +1272,11 @@ class SAServeEngine:
             seg = np.empty((n_padded * cps,), np.int32)
             adopt = np.empty((n_padded * cps,), bool)
             for b, (s, job) in enumerate(slot_list):
-                kid_blk[b] = np.int32(job.req.kid)
-                if is_qap:
-                    inst = job.req.instance
-                    F_blk[b * dim:(b + 1) * dim] = inst.F
-                    D_blk[b * dim:(b + 1) * dim] = inst.D
                 is_pa = job.req.method == "pa"
                 t = job.T
                 for i in range(K):
-                    # float64 iteration, f32 per level — identical to
-                    # K=1's pack-then-advance of the float ``job.T`` cursor.
+                    # float64 iteration, f32 per level — identical to the
+                    # collect's advance of the float ``job.T`` cursor.
                     T_lvls[i, b] = t
                     if is_pa:
                         dbeta_lvls[i, b] = _pa_dbeta(t, job.req.rho)
@@ -1486,13 +1290,10 @@ class SAServeEngine:
                 adopt[b * cps:(b + 1) * cps] = (
                     job.req.method == "sa" and job.req.exchange == "sync")
             for b in range(n_blocks, n_padded):
-                # Pad blocks are *dead* (zero planned levels): pure
-                # pass-through, so whatever a reused buffer holds in its
-                # pad rows is legal — they cost lanes, not correctness.
-                kid_blk[b] = kid_blk[0]
-                if is_qap:
-                    F_blk[b * dim:(b + 1) * dim] = F_blk[:dim]
-                    D_blk[b * dim:(b + 1) * dim] = D_blk[:dim]
+                # Pad blocks are *dead* (zero planned levels) and claim the
+                # reserved segment n_slots: pure pass-through, so whatever
+                # a reused buffer holds in its pad rows is legal — they
+                # cost lanes, not correctness.
                 T_lvls[:, b] = T_lvls[:, 0]
                 seed_blk[b] = seed_blk[0]
                 step0_blk[b] = step0_blk[0]
@@ -1500,40 +1301,30 @@ class SAServeEngine:
                 levels_blk[b] = 0
                 seg[b * cps:(b + 1) * cps] = self.cfg.n_slots
                 adopt[b * cps:(b + 1) * cps] = False
-            mcode, t_rung, partner2, pairlo2, seg_lo, seg_hi = \
-                self._pack_class_controls(jobs, n_padded, 2)
+            fam_ops = fam.block_operands([job.req for _, job in slot_list],
+                                         n_padded)
+            mcode, t_rung, partner, pairlo, seg_lo, seg_hi = \
+                self._pack_class_controls(jobs, n_padded, min(K, 2))
 
+        # Committed transfers pin the group's program to the shard's mesh
+        # device.  The call returns device arrays without blocking; the
+        # collect pass reads the champions after every shard has launched.
         x_dev = self._group_state(shard, (family, dim, n_steps), slot_list,
                                   n_padded)
         with sub("dispatch.h2d", shard.index):
             # One batched transfer for all control arrays: separate
             # device_put dispatches were the dominant per-launch host cost
             # once the state buffer started cache-hitting.
-            if is_qap:
-                ctrl = jax.device_put(
-                    (F_blk, D_blk, T_lvls, seed_blk, step0_blk, base_blk,
-                     levels_blk, lvl0_blk, seg, adopt, mcode, t_rung,
-                     partner2, pairlo2, seg_lo, seg_hi), shard.device)
-            else:
-                ctrl = jax.device_put(
-                    (kid_blk, T_lvls, seed_blk, step0_blk, base_blk,
-                     levels_blk, lvl0_blk, dbeta_lvls, seg, adopt, mcode,
-                     t_rung, partner2, pairlo2, seg_lo, seg_hi), shard.device)
+            ctrl = jax.device_put(
+                (fam_ops, T_lvls, dbeta_lvls, seed_blk, step0_blk, base_blk,
+                 levels_blk, lvl0_blk, seg, adopt, mcode, t_rung, partner,
+                 pairlo, seg_lo, seg_hi), shard.device)
         with sub("dispatch.launch", shard.index):
-            if is_qap:
-                outs = _group_tick_qap_fused(
-                    x_dev, *ctrl,
-                    k=K, n_steps=n_steps, blk=cps,
-                    use_pallas=self._use_pallas,
-                    interpret=self.cfg.interpret,
-                    num_segments=self.cfg.n_slots + 1)
-            else:
-                outs = _group_tick_fused(
-                    x_dev, *ctrl,
-                    k=K, n_steps=n_steps, blk=cps, variant=self.cfg.variant,
-                    use_pallas=self._use_pallas,
-                    interpret=self.cfg.interpret,
-                    num_segments=self.cfg.n_slots + 1)
+            outs = _group_tick(
+                x_dev, fam, *ctrl, n_steps=n_steps, blk=cps,
+                variant=self.cfg.variant, use_pallas=self._use_pallas,
+                interpret=self.cfg.interpret,
+                num_segments=self.cfg.n_slots + 1)
         if tel.enabled:
             live = sum(planned[job.rid] * len(job.slots) for job in jobs)
             tel.m_block_steps.inc(live * n_steps, "live")
@@ -1544,114 +1335,10 @@ class SAServeEngine:
                                n_padded, outs[0])
         return shard, n_steps, jobs, outs, planned
 
-    def _launch_group(self, shard: EngineShard, family: str, dim: int,
-                      n_steps: int, jobs: List[ActiveJob]):
-        """Pack the group's controls, reuse (or rebuild) its device state
-        buffer, and launch its one-level device program (async); returns
-        the collect-pass arguments.  ``family`` picks the program
-        (Metropolis vs QAP pairwise-exchange) and the state dtype; see
-        :meth:`_launch_group_fused`."""
-        cps = self.cfg.chains_per_slot
-        is_qap = family == fam_mod.FAMILY_PERMUTATION
-        slot_list: List[Tuple[int, ActiveJob]] = [
-            (s, job) for job in jobs for s in job.slots]
-        n_blocks = len(slot_list)
-        # Pad to a power of two of blocks so the number of compiled
-        # signatures per (family, dim, N) is O(log n_slots), not
-        # O(n_slots).
-        n_padded = 1
-        while n_padded < n_blocks:
-            n_padded *= 2
-
-        tel = self.telemetry
-        sub = self._sub
-        with sub("dispatch.pack", shard.index):
-            kid_blk = np.empty((n_padded,), np.int32)
-            if is_qap:
-                F_blk = np.empty((n_padded * dim, dim), np.float32)
-                D_blk = np.empty((n_padded * dim, dim), np.float32)
-            T_blk = np.empty((n_padded,), np.float32)
-            dbeta_blk = np.zeros((n_padded,), np.float32)
-            seed_blk = np.empty((n_padded,), np.uint32)
-            step0_blk = np.empty((n_padded,), np.uint32)
-            base_blk = np.empty((n_padded,), np.uint32)
-            lvl0_blk = np.zeros((n_padded,), np.uint32)
-            seg = np.empty((n_padded * cps,), np.int32)
-            adopt = np.empty((n_padded * cps,), bool)
-            for b, (s, job) in enumerate(slot_list):
-                kid_blk[b] = np.int32(job.req.kid)
-                if is_qap:
-                    inst = job.req.instance
-                    F_blk[b * dim:(b + 1) * dim] = inst.F
-                    D_blk[b * dim:(b + 1) * dim] = inst.D
-                T_blk[b] = job.T
-                if job.req.method == "pa":
-                    dbeta_blk[b] = _pa_dbeta(job.T, job.req.rho)
-                seed_blk[b] = np.uint32(job.req.seed)
-                step0_blk[b] = np.uint32(job.steps_done)
-                base_blk[b] = shard.pool.chain_base[s]
-                lvl0_blk[b] = np.uint32(job.level)
-                seg[b * cps:(b + 1) * cps] = job.rid
-                adopt[b * cps:(b + 1) * cps] = (
-                    job.req.method == "sa" and job.req.exchange == "sync")
-            # Pad blocks run block 0's controls, claim the reserved
-            # segment n_slots and never adopt: whatever state their rows
-            # hold (a copy of block 0 when packed, a reused buffer's old
-            # rows otherwise) costs lanes, not correctness.
-            for b in range(n_blocks, n_padded):
-                kid_blk[b] = kid_blk[0]
-                if is_qap:
-                    F_blk[b * dim:(b + 1) * dim] = F_blk[:dim]
-                    D_blk[b * dim:(b + 1) * dim] = D_blk[:dim]
-                T_blk[b] = T_blk[0]
-                seed_blk[b] = seed_blk[0]
-                step0_blk[b] = step0_blk[0]
-                base_blk[b] = base_blk[0]
-                seg[b * cps:(b + 1) * cps] = self.cfg.n_slots
-                adopt[b * cps:(b + 1) * cps] = False
-            mcode, t_rung, partner, pairlo, seg_lo, seg_hi = \
-                self._pack_class_controls(jobs, n_padded, 1)
-
-        # Committed transfers pin the group's program to the shard's mesh
-        # device.  The call returns device arrays without blocking; the
-        # collect pass reads the champions after every shard has launched.
-        x_dev = self._group_state(shard, (family, dim, n_steps), slot_list,
-                                  n_padded)
-        with sub("dispatch.h2d", shard.index):
-            if is_qap:
-                ctrl = jax.device_put(
-                    (F_blk, D_blk, T_blk, seed_blk, step0_blk, base_blk,
-                     lvl0_blk, seg, adopt, mcode, t_rung, partner[0],
-                     pairlo[0], seg_lo, seg_hi), shard.device)
-            else:
-                ctrl = jax.device_put(
-                    (kid_blk, T_blk, seed_blk, step0_blk, base_blk, lvl0_blk,
-                     dbeta_blk, seg, adopt, mcode, t_rung, partner[0],
-                     pairlo[0], seg_lo, seg_hi), shard.device)
-        with sub("dispatch.launch", shard.index):
-            if is_qap:
-                outs = _group_tick_qap(
-                    x_dev, *ctrl, n_steps=n_steps, blk=cps,
-                    use_pallas=self._use_pallas,
-                    interpret=self.cfg.interpret,
-                    num_segments=self.cfg.n_slots + 1)
-            else:
-                outs = _group_tick(
-                    x_dev, *ctrl, n_steps=n_steps, blk=cps,
-                    variant=self.cfg.variant, use_pallas=self._use_pallas,
-                    interpret=self.cfg.interpret,
-                    num_segments=self.cfg.n_slots + 1)
-        if tel.enabled:
-            tel.m_block_steps.inc(n_blocks * n_steps, "live")
-            tel.m_block_steps.inc((n_padded - n_blocks) * n_steps, "padded")
-        self._keep_group_state(shard, (family, dim, n_steps), slot_list,
-                               n_padded, outs[0])
-        return shard, n_steps, jobs, outs
-
     def _group_state(self, shard: EngineShard, key: Tuple[str, int, int],
                      slot_list: List[Tuple[int, ActiveJob]], n_padded: int):
         """The group's packed chain state on the shard's device, for a
-        launch that donates it (both launch paths).
+        launch that donates it.
 
         The double buffer: if every slot of the group still references
         this group's cached output buffer at its packed rows, with the same
@@ -1681,8 +1368,7 @@ class SAServeEngine:
                     return buf
             read0 = pool.bytes_read
             x = np.empty((n_padded * cps, dim),
-                         np.int32 if family == fam_mod.FAMILY_PERMUTATION
-                         else np.float32)
+                         fam_mod.get_family(family).state_dtype)
             for b, (s, _job) in enumerate(slot_list):
                 x[b * cps:(b + 1) * cps] = pool.get_block(s)
             for b in range(len(slot_list), n_padded):
@@ -1710,12 +1396,10 @@ class SAServeEngine:
     def _finish_reason(self, job: ActiveJob) -> Optional[str]:
         req = job.req
         if req.target_error is not None:
-            # submit() guarantees the optimum exists; .get keeps the tick
-            # loop un-wedgeable even if F_OPT is mutated under a live job.
-            # Permutation requests read the instance's best_known instead.
-            f_opt = (F_OPT.get(req.kid)
-                     if req.family == fam_mod.FAMILY_CONTINUOUS
-                     else req.f_opt)
+            # submit() guarantees the optimum exists; a family answers None
+            # rather than raising, so the tick loop stays un-wedgeable even
+            # if its table is mutated under a live job.
+            f_opt = req.f_opt
             if f_opt is not None and job.best_f <= f_opt + req.target_error:
                 return "target"
         if req.max_evals is not None and job.evals >= req.max_evals:
@@ -1735,12 +1419,9 @@ class SAServeEngine:
         return job.levels_limit or job.req.n_levels
 
     def _retire(self, shard: EngineShard, job: ActiveJob, reason: str,
-                finish_tick: Optional[int] = None) -> None:
-        # finish_tick is on the ladder-level clock: the K=1 path passes
-        # the current tick; the fused path passes boundary + counted - 1
-        # (the level at which the finish reason actually fired).
-        if finish_tick is None:
-            finish_tick = self.tick_count
+                finish_tick: int) -> None:
+        # finish_tick is on the ladder-level clock: boundary + counted - 1,
+        # the level at which the finish reason actually fired.
         self.results.append(RequestResult(
             req_id=job.req.req_id, objective=job.req.objective,
             dim=job.req.dim, x_best=job.best_x, f_best=job.best_f,

@@ -23,7 +23,10 @@ import pytest
 from repro.service import (ArrivalProcess, EngineConfig, SARequest,
                            SAServeEngine, Telemetry, latency_summary,
                            run_standalone)
-from repro.service.engine import _group_tick_fused
+import inspect
+
+from repro.service import engine as engine_mod
+from repro.service.engine import _group_tick
 
 CPS = 8
 K_VALUES = (2, 4, 8)
@@ -118,14 +121,15 @@ def test_k_exceeding_remaining_levels_truncates_cleanly():
 
 
 def test_k1_degenerate_path_compiles_no_fused_programs():
-    """macro_k=1 keeps the one-level program (no fused program is traced)
-    but shares the fused path's state residency: after a launch every
-    active slot holds a device ref into its group's cached output
-    buffer, and the dispatch cache is armed."""
-    if not (hasattr(_group_tick_fused, "clear_cache")
-            and hasattr(_group_tick_fused, "_cache_size")):
+    """macro_k=1 compiles no program of its own: K=1 and K=4 both run the
+    one ``_group_tick`` (one compiled entry per group shape for each k),
+    and K=1 keeps the state residency: after a launch every active slot
+    holds a device ref into its group's cached output buffer, and the
+    dispatch cache is armed."""
+    if not (hasattr(_group_tick, "clear_cache")
+            and hasattr(_group_tick, "_cache_size")):
         pytest.skip("kernel cache introspection unavailable")
-    _group_tick_fused.clear_cache()
+    _group_tick.clear_cache()
     cfg = _cfg(k=1, n_devices=2)
     engine = SAServeEngine(cfg)
     for r in _mix():
@@ -140,10 +144,39 @@ def test_k1_degenerate_path_compiles_no_fused_programs():
                     ref = shard.pool.device_ref(s)
                     assert ref is not None and id(ref.buf) in bufs
     engine.run(max_ticks=2000)
-    assert _group_tick_fused._cache_size() == 0
+    at_k1 = _group_tick._cache_size()
+    assert at_k1 >= 1
     _, engine, _ = _serve(_mix(), k=4)
-    assert _group_tick_fused._cache_size() >= 1
+    assert _group_tick._cache_size() == 2 * at_k1
     assert any(s.group_cache for s in engine.shards)
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_launch_uploads_one_partner_row_per_parity_in_use(k, monkeypatch):
+    """The controls a launch hands the group program: ``k`` temperature
+    rows, one level cursor per block, and ``min(k, 2)`` rows of PT
+    partners and pair keys — one row at K=1, where the program never
+    reads a second parity."""
+    seen = []
+
+    def spy(*args, **kwargs):
+        bound = inspect.signature(_group_tick.__wrapped__).bind(*args,
+                                                                **kwargs)
+        seen.append({name: np.shape(v)
+                     for name, v in bound.arguments.items()
+                     if name not in ("family", "fam_ops")})
+        return _group_tick(*args, **kwargs)
+
+    monkeypatch.setattr(engine_mod, "_group_tick", spy)
+    # One 2-slot request plus a 1-slot one: 3 blocks, padded to 4.
+    _serve([_req(0, n_chains=2 * CPS), _req(1, method="pt")], k=k,
+           n_devices=1)
+    assert seen
+    n_padded, rows = 4, min(k, 2)
+    for shapes in seen:
+        assert shapes["partner"] == shapes["pairlo"] == (rows, n_padded * CPS)
+        assert shapes["T_lvls"] == shapes["dbeta_lvls"] == (k, n_padded)
+        assert shapes["levels_blk"] == (n_padded,)
 
 
 # ----------------------------------------------------- boundary decisions

@@ -277,59 +277,44 @@ def test_forced_migration_bit_exact():
 
 
 # ---------------------------------------------------- compile stability
-def test_one_compiled_program_per_family():
-    """A mixed continuous+QAP fleet compiles exactly one sweep program
-    per family: the continuous group keeps its runtime-kid dispatch, the
-    QAP group types on int32 states — neither family's tenants retrace
-    the other's program."""
-    from repro.service.engine import _group_tick, _group_tick_qap
-    can_count = all(
-        hasattr(f, a) for f in (_group_tick, _group_tick_qap)
-        for a in ("clear_cache", "_cache_size"))
-    if not can_count:
+def _group_programs_per_family(cfg, reqs):
+    """Serve ``reqs`` from a cleared program cache; return the number of
+    ``_group_tick`` signatures compiled, each request checked bit-exact
+    against its standalone run."""
+    from repro.service.engine import _group_tick
+    if not all(hasattr(_group_tick, a)
+               for a in ("clear_cache", "_cache_size")):
         pytest.skip("jax jit cache introspection API unavailable")
-    cfg = _cfg(n_slots=6)
     engine = SAServeEngine(cfg)
+    for r in reqs:
+        engine.submit(r)
+    _group_tick.clear_cache()
+    results = {r.req_id: r for r in engine.run(max_ticks=200)}
+    compiled = _group_tick._cache_size()   # before the standalone runs
+    assert len(results) == len(reqs)
+    for r in reqs:
+        _assert_bit_exact(results[r.req_id], run_standalone(r, cfg))
+    return compiled
+
+
+def test_one_compiled_program_per_family():
+    """A mixed continuous+QAP fleet compiles the one group program once
+    per family: the continuous group keeps its runtime-kid dispatch, the
+    QAP group its runtime instance operands and int32 states — neither
+    family's tenants retrace the other's signature."""
     # Both QAP tenants on one instance (one (family, dim, N) group); three
     # continuous objectives at one (dim, N).
     reqs = [_req(0, "syn10"), _req(1, "syn10", seed=222, T0=20.0),
             _creq(2), _creq(3, objective="ackley"),
             _creq(4, objective="griewank")]
-    for r in reqs:
-        engine.submit(r)
-    _group_tick.clear_cache()
-    _group_tick_qap.clear_cache()
-    results = {r.req_id: r for r in engine.run(max_ticks=200)}
-    assert len(results) == len(reqs)
-    assert _group_tick._cache_size() == 1
-    assert _group_tick_qap._cache_size() == 1
-    for r in reqs:
-        _assert_bit_exact(results[r.req_id], run_standalone(r, cfg))
+    assert _group_programs_per_family(_cfg(n_slots=6), reqs) == 2
 
 
 def test_one_fused_program_per_family():
     """Same pin under macro-tick fusion (K=4, donated buffers)."""
-    from repro.service.engine import (_group_tick_fused,
-                                      _group_tick_qap_fused)
-    can_count = all(
-        hasattr(f, a) for f in (_group_tick_fused, _group_tick_qap_fused)
-        for a in ("clear_cache", "_cache_size"))
-    if not can_count:
-        pytest.skip("jax jit cache introspection API unavailable")
-    cfg = _cfg(n_slots=6, macro_k=4)
-    engine = SAServeEngine(cfg)
     reqs = [_req(0, "syn10"), _req(1, "syn10", seed=222, T0=20.0),
             _creq(2), _creq(3, objective="ackley")]
-    for r in reqs:
-        engine.submit(r)
-    _group_tick_fused.clear_cache()
-    _group_tick_qap_fused.clear_cache()
-    results = {r.req_id: r for r in engine.run(max_ticks=200)}
-    assert len(results) == len(reqs)
-    assert _group_tick_fused._cache_size() == 1
-    assert _group_tick_qap_fused._cache_size() == 1
-    for r in reqs:
-        _assert_bit_exact(results[r.req_id], run_standalone(r, cfg))
+    assert _group_programs_per_family(_cfg(n_slots=6, macro_k=4), reqs) == 2
 
 
 # ------------------------------------------------- eager validation (sat)

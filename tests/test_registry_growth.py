@@ -23,9 +23,9 @@ from repro.kernels import objective_math as om
 from repro.kernels import ref
 from repro.kernels.metropolis_sweep import metropolis_sweep_pallas
 from repro.objectives import functions as F
+from repro.objectives.families import F_OPT_BY_NAME
 from repro.service import (
     EngineConfig,
-    F_OPT,
     SARequest,
     SAServeEngine,
     SERVABLE,
@@ -62,15 +62,16 @@ def _cfg(**kw):
 
 def test_registry_is_widened_consistently():
     """Every registry surface agrees on the two new objectives: names,
-    kids, boxes, host-suite kernel_id backlinks and F_OPT optima."""
+    kids, boxes, host-suite kernel_id backlinks and F_OPT_BY_NAME optima."""
     assert set(NEW_NAMES) <= set(SERVABLE)
     assert om.N_KIDS == 6
-    assert set(F_OPT) == set(om.KID_BY_NAME.values())
+    assert set(F_OPT_BY_NAME) == set(om.KID_BY_NAME)
     assert set(om.BOX) == set(om.KID_BY_NAME.values())
     for kid, maker in NEW_KIDS.items():
         obj = maker(8)
         assert obj.kernel_id == kid
-        assert obj.f_opt == F_OPT[kid]
+        name = next(n for n, k in om.KID_BY_NAME.items() if k == kid)
+        assert obj.f_opt == F_OPT_BY_NAME[name]
         lo, hi = om.BOX[kid]
         assert (obj.lower[0], obj.upper[0]) == (lo, hi)
 
@@ -149,16 +150,16 @@ def test_new_objectives_serve_bit_exact_and_share_one_program():
 @pytest.mark.parametrize("dim,n_steps,macro_k", [(4, 10, 4), (8, 10, 4), (4, 10, 2)])
 def test_fused_macro_tick_compiles_one_program_per_shape(dim, n_steps, macro_k):
     """Compile stability under macro-tick fusion: co-batching all six
-    SERVABLE objectives at one (dim, N, K) traces exactly ONE fused
+    SERVABLE objectives at one (dim, N, K) traces exactly ONE group
     program — the K-level loop keeps the objective id a runtime input —
     and every champion stays bit-exact vs standalone."""
-    from repro.service.engine import _group_tick_fused
+    from repro.service.engine import _group_tick
 
-    can_count = hasattr(_group_tick_fused, "clear_cache") and hasattr(
-        _group_tick_fused, "_cache_size"
+    can_count = hasattr(_group_tick, "clear_cache") and hasattr(
+        _group_tick, "_cache_size"
     )
     if can_count:
-        _group_tick_fused.clear_cache()
+        _group_tick.clear_cache()
     cfg = _cfg(n_slots=6, macro_k=macro_k)
     engine = SAServeEngine(cfg)
     reqs = [_req(i, obj, dim=dim, N=n_steps) for i, obj in enumerate(ALL_NAMES)]
@@ -167,8 +168,8 @@ def test_fused_macro_tick_compiles_one_program_per_shape(dim, n_steps, macro_k):
     results = {r.req_id: r for r in engine.run(max_ticks=200)}
     assert len(results) == 6
     if can_count:
-        # One fused lowering serves the whole registry at this shape.
-        assert _group_tick_fused._cache_size() == 1
+        # One lowering serves the whole registry at this shape.
+        assert _group_tick._cache_size() == 1
     for r in reqs:
         solo = run_standalone(r, cfg)
         assert results[r.req_id].f_best == solo.f_best
@@ -183,11 +184,11 @@ def test_new_objectives_anneal_toward_their_optimum(name):
     res = run_standalone(req, _cfg())
     x0_best = float(np.min(om.full_eval(req.kid, _x0(req.kid, CPS, 4), 4)))
     assert res.f_best <= x0_best + 1e-6, "annealing never improved"
-    assert res.f_best >= F_OPT[req.kid] - 1e-5, "beat the global optimum?!"
+    assert res.f_best >= F_OPT_BY_NAME[name] - 1e-5, "beat the global optimum?!"
 
 
 def test_target_error_supported_on_new_objectives():
-    """F_OPT registration makes accuracy-target stopping legal for the
+    """F_OPT_BY_NAME registration makes accuracy-target stopping legal for the
     new objectives (the submit-time guard must not fire)."""
     engine = SAServeEngine(_cfg())
     engine.submit(_req(0, "exponential", target_error=10.0, T0=10.0, rho=0.5))

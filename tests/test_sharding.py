@@ -423,11 +423,10 @@ def test_target_error_requires_registered_optimum():
     """Satellite: target_error on an objective without a known optimum is
     a typed submit-time error, not a mid-tick KeyError that wedges the
     slot."""
-    import repro.service.engine as eng_mod
-    from repro.kernels import objective_math as om
+    from repro.objectives.families import F_OPT_BY_NAME
 
     engine = SAServeEngine(_cfg(n_slots=2, n_devices=1))
-    saved = eng_mod.F_OPT.pop(om.KID_ACKLEY)
+    saved = F_OPT_BY_NAME.pop("ackley")
     try:
         with pytest.raises(ValueError, match="target_error"):
             engine.submit(_req(0, objective="ackley", target_error=0.5))
@@ -440,12 +439,12 @@ def test_target_error_requires_registered_optimum():
         assert results[1].completed
         assert results[2].finish_reason == "target"
     finally:
-        eng_mod.F_OPT[om.KID_ACKLEY] = saved
+        F_OPT_BY_NAME["ackley"] = saved
 
 
 def test_every_registry_objective_has_an_optimum():
-    """The guard can only fire if registry growth forgets F_OPT; today the
-    two must agree exactly."""
+    """The guard can only fire if registry growth forgets an optimum in
+    F_OPT_BY_NAME; today the two must agree exactly."""
     from repro.kernels import objective_math as om
-    from repro.service.engine import F_OPT
-    assert set(F_OPT) == set(om.KID_BY_NAME.values())
+    from repro.objectives.families import F_OPT_BY_NAME
+    assert set(F_OPT_BY_NAME) == set(om.KID_BY_NAME)

@@ -43,6 +43,7 @@ from repro.service import (
     run_standalone,
     validate_trace,
 )
+from repro.objectives.families import get_family
 from repro.service.engine import _group_tick
 from repro.service.telemetry import (Histogram, MetricsRegistry,
                                      compile_seconds)
@@ -524,22 +525,30 @@ def test_compiles_are_counted_in_the_phase_that_ran_them():
 
 def test_device_scopes_name_the_group_program_stages():
     """The scopes are in the lowered program's op metadata: the sweep, the
-    controls, the exchange and each exchange stage."""
-    cps, n = CPS, 4 * CPS
+    controls, the exchange and each exchange stage — with either family's
+    sweep inside the one group program."""
+    cps, nb, k, dim = CPS, 4, 2, 4
+    n = nb * cps
     f32, u32, i32 = np.float32, np.uint32, np.int32
-    args = (np.zeros((n, 4), f32), np.zeros(4, i32), np.ones(4, f32),
-            np.zeros(4, u32), np.zeros(4, u32), np.zeros(4, u32),
-            np.zeros(4, u32), np.zeros(4, f32), np.zeros(n, i32),
-            np.ones(n, bool), np.zeros(n, np.int8), np.ones(n, f32),
-            np.arange(n, dtype=i32), np.zeros(n, u32),
-            np.arange(n, dtype=i32), np.arange(1, n + 1, dtype=i32))
-    text = _group_tick.lower(
-        *args, n_steps=10, blk=cps, variant="delta", use_pallas=False,
-        interpret=False, num_segments=5).as_text(debug_info=True)
-    for scope in ("sa.sweep", "sa.controls", "sa.exchange/champion",
-                  "sa.exchange/adopt", "sa.exchange/pt_swap",
-                  "sa.exchange/pa_resample"):
-        assert scope in text, scope
+    family_ops = {"continuous": (np.zeros(nb, i32),),
+                  "permutation": (np.zeros((nb * dim, dim), f32),) * 2}
+    for family, fam_ops in family_ops.items():
+        fam = get_family(family)
+        args = (np.zeros((n, dim), fam.state_dtype), fam, fam_ops,
+                np.ones((k, nb), f32), np.zeros((k, nb), f32),
+                np.zeros(nb, u32), np.zeros(nb, u32), np.zeros(nb, u32),
+                np.ones(nb, i32), np.zeros(nb, u32), np.zeros(n, i32),
+                np.ones(n, bool), np.zeros(n, np.int8), np.ones(n, f32),
+                np.tile(np.arange(n, dtype=i32), (2, 1)),
+                np.zeros((2, n), u32), np.arange(n, dtype=i32),
+                np.arange(1, n + 1, dtype=i32))
+        text = _group_tick.lower(
+            *args, n_steps=10, blk=cps, variant="delta", use_pallas=False,
+            interpret=False, num_segments=5).as_text(debug_info=True)
+        for scope in ("sa.sweep", "sa.controls", "sa.exchange/champion",
+                      "sa.exchange/adopt", "sa.exchange/pt_swap",
+                      "sa.exchange/pa_resample"):
+            assert scope in text, (family, scope)
 
 
 def test_profiler_trace_holds_the_phase_and_subspan_annotations(tmp_path):

@@ -64,7 +64,7 @@ SUITE_DIMS = [4, 8, 10, 30, 100, 200, 400, 512]
 @pytest.mark.parametrize("variant", ["delta", "full"])
 def test_metropolis_sweep_compiles(one_chip, variant, dim):
     """The engine's form: runtime objective id, level cursor and per-chain
-    temperatures, as ``_group_tick_fused`` calls it."""
+    temperatures, as ``_group_tick`` calls it."""
     def sweep(x, kid, T, seed, step0, base, live, t_chain):
         return metropolis_sweep_pallas(
             x, T, seed, step0, kid=kid, n_steps=100, blk=BLK,
